@@ -6,9 +6,10 @@ rmsnorm — fused norm (one HBM round trip).
 ssm_scan — chunked diagonal linear recurrence (Mamba/mLSTM core), carried
     through VMEM scratch across the sequential time grid.
 
-Kernels target TPU (pl.pallas_call + BlockSpec); CPU validation runs them
-in interpret mode against the ref.py oracles (tests/test_kernels.py sweeps
-shapes and dtypes).
+Kernels compile for the TPU by default (``interpret=False``). CPU
+validation passes ``interpret=True`` and checks them against the ref.py
+oracles (tests/test_kernels.py sweeps shapes and dtypes);
+tests/test_tpu_compile.py compiles them for a described v5e.
 """
 from .flash_attention.ops import flash_mha
 from .rmsnorm.kernel import fused_rmsnorm

@@ -80,7 +80,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     BH, Sq, hd = q.shape
     BKV, Sk, _ = k.shape

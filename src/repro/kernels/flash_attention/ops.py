@@ -2,7 +2,7 @@
 
 ``flash_mha(q, k, v)`` takes the model's [B, S, H, hd] / [B, S, KV, hd]
 layout, flattens heads into the batch dim, dispatches to the Pallas kernel
-(interpret-mode on CPU; compiled on TPU) and restores the layout.
+(compiled for the TPU; ``interpret=True`` on the CPU) and restores the layout.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ __all__ = ["flash_mha"]
                                              "interpret"))
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True, block_q: int = 128, block_k: int = 128,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool = False) -> jax.Array:
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV

@@ -24,7 +24,7 @@ def rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def fused_rmsnorm(x: jax.Array, w: jax.Array, *, eps: float = 1e-6,
-                  block_rows: int = 256, interpret: bool = True) -> jax.Array:
+                  block_rows: int = 256, interpret: bool = False) -> jax.Array:
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = 1

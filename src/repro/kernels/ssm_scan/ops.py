@@ -13,7 +13,7 @@ __all__ = ["ssm_scan_batched"]
 @functools.partial(jax.jit, static_argnames=("block_t", "block_c",
                                              "interpret"))
 def ssm_scan_batched(a: jax.Array, b: jax.Array, *, block_t: int = 128,
-                     block_c: int = 512, interpret: bool = True) -> jax.Array:
+                     block_c: int = 512, interpret: bool = False) -> jax.Array:
     """a, b [B, S, C] (or [S, C]) -> h, scanning axis -2."""
     if a.ndim == 2:
         return ssm_scan(a, b, block_t=block_t, block_c=block_c,

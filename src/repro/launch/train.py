@@ -23,6 +23,7 @@ from ..models import build_model
 from ..train import (CheckpointManager, SyntheticData, init_state,
                      latest_step, make_train_step, restore_checkpoint,
                      schedule_for)
+from .compile_cache import enable_compile_cache
 
 __all__ = ["main", "train"]
 
@@ -99,6 +100,7 @@ def main(argv=None):
     p.add_argument("--bf16", action="store_true")
     p.add_argument("--lr", type=float, default=3e-4)
     args = p.parse_args(argv)
+    enable_compile_cache()
     train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
           reduced=args.reduced, ckpt_dir=args.ckpt_dir,
           save_every=args.save_every, microbatches=args.microbatches,

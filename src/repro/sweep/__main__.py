@@ -24,6 +24,7 @@ import json
 import os
 import sys
 
+from ..launch.compile_cache import enable_compile_cache
 from .runner import run_campaign, save_result
 from .spec import builtin_spec_names, load_builtin_spec, load_spec
 
@@ -321,6 +322,7 @@ def main(argv=None) -> int:
     hp.set_defaults(fn=cmd_crosscheck_hlo)
 
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return args.fn(args)
 
 

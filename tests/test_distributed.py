@@ -18,11 +18,7 @@ from repro.serve.engine import ServeEngine
 
 
 def _mesh(shape=(16, 16), axes=("data", "model")):
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        # older jaxlib: AbstractMesh(((name, size), ...)) pair form
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(shape, axes)
 
 
 def test_rules_divisibility_head_tp():

@@ -45,8 +45,6 @@ def test_scan_trip_count_vs_unrolled():
     assert s_unroll.dot_flops == pytest.approx(expected, rel=0.01)
     # cross-check against XLA's analysis of the unrolled module
     ca = jax.jit(f_unroll).lower(x, w).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jaxlib: one dict per device
-        ca = ca[0]
     assert s_unroll.dot_flops == pytest.approx(ca["flops"], rel=0.05)
 
 

@@ -37,7 +37,7 @@ def test_flash_attention_sweep(B, S, H, KV, hd, causal):
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, KV, hd), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, KV, hd), jnp.float32)
-    out = flash_mha(q, k, v, causal=causal)
+    out = flash_mha(q, k, v, causal=causal, interpret=True)
     ref = _mha_ref(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -48,7 +48,7 @@ def test_flash_attention_bf16():
     q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.bfloat16)
     k = jax.random.normal(ks[1], (1, 256, 2, 64), jnp.bfloat16)
     v = jax.random.normal(ks[2], (1, 256, 2, 64), jnp.bfloat16)
-    out = flash_mha(q, k, v, causal=True).astype(jnp.float32)
+    out = flash_mha(q, k, v, causal=True, interpret=True).astype(jnp.float32)
     ref = _mha_ref(q, k, v, True).astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-2, atol=3e-2)
@@ -60,8 +60,8 @@ def test_flash_attention_block_invariance():
     q = jax.random.normal(ks[0], (1, 256, 2, 64), jnp.float32)
     k = jax.random.normal(ks[1], (1, 256, 2, 64), jnp.float32)
     v = jax.random.normal(ks[2], (1, 256, 2, 64), jnp.float32)
-    a = flash_mha(q, k, v, block_q=64, block_k=64)
-    b = flash_mha(q, k, v, block_q=128, block_k=256)
+    a = flash_mha(q, k, v, block_q=64, block_k=64, interpret=True)
+    b = flash_mha(q, k, v, block_q=128, block_k=256, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-5)
 
@@ -74,7 +74,7 @@ def test_flash_attention_block_invariance():
 def test_rmsnorm_sweep(shape, dtype):
     x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
     w = jax.random.normal(jax.random.PRNGKey(1), shape[-1:], dtype)
-    out = fused_rmsnorm(x, w).astype(jnp.float32)
+    out = fused_rmsnorm(x, w, interpret=True).astype(jnp.float32)
     ref = rmsnorm_ref(x, w).astype(jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
@@ -88,7 +88,7 @@ def test_ssm_scan_property(S, C):
     """Property: kernel == associative-scan oracle across shapes."""
     a = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(S), (S, C)))
     b = jax.random.normal(jax.random.PRNGKey(C), (S, C))
-    out = ssm_scan_batched(a, b)
+    out = ssm_scan_batched(a, b, interpret=True)
     ref = ssm_scan_ref(a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
@@ -97,7 +97,22 @@ def test_ssm_scan_property(S, C):
 def test_ssm_scan_batched_3d():
     a = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(0), (2, 64, 96)))
     b = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 96))
-    out = ssm_scan_batched(a, b)
+    out = ssm_scan_batched(a, b, interpret=True)
     ref = jax.vmap(ssm_scan_ref)(a, b)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("S,C,dtype", [
+    (37, 200, jnp.bfloat16),   # 16-row bf16 tiles, padded time and channels
+    (256, 640, jnp.float32),   # two time blocks, two channel blocks
+])
+def test_ssm_scan_tiles(S, C, dtype):
+    a = jax.nn.sigmoid(jax.random.normal(jax.random.PRNGKey(3), (S, C)))
+    b = jax.random.normal(jax.random.PRNGKey(4), (S, C))
+    a, b = a.astype(dtype), b.astype(dtype)
+    out = ssm_scan_batched(a, b, interpret=True).astype(jnp.float32)
+    ref = ssm_scan_ref(a, b).astype(jnp.float32)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=tol, atol=tol)

@@ -64,12 +64,13 @@ MULTIDEV_SNIPPET = textwrap.dedent("""
     import jax, numpy as np
     import jax.numpy as jnp
     from repro.configs import REGISTRY, SHAPES
+    from repro.launch.mesh import make_mesh
     from repro.launch.programs import build_program
     from repro.train.data import SyntheticData
 
     cfg = REGISTRY["qwen2-1.5b"].reduced()
     shape = SHAPES["train_4k"]
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     prog = build_program(cfg, shape, mesh)
     # run REAL values through the partitioned program on 8 fake devices
     # (jit bakes shardings, not shapes — a smaller batch recompiles fine)

@@ -1,0 +1,102 @@
+"""Compile the chip's programs for a described (not attached) TPU v5e.
+
+The TPU compiler is installed with JAX, so these run without a chip: they
+refuse what interpret mode accepts (unaligned slices, loops Mosaic cannot
+lower, too much VMEM). Each kernel compiles at the real widths of the
+models that use it and must lower to a Mosaic ``tpu_custom_call``; the
+campaign pre-screen compiles for one ``lm_full_pod`` layer body.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and pytest-xdist workers all import
+this file. The persistent compilation cache is off around these
+compiles, since an entry written for a described chip cannot be read back
+without one.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+            from jax.experimental import topologies
+            try:
+                topo = topologies.get_topology_desc(
+                    platform="tpu", topology_name="v5e:2x2")
+            except Exception as e:
+                pytest.skip(f"no v5e:2x2 topology can be described: {e}")
+            yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_flash_attention_compiles_qwen2_widths(one_chip):
+    from repro.kernels.flash_attention.ops import flash_mha
+
+    q = _sds(one_chip, (4, 2048, 12, 128), jnp.bfloat16)
+    kv = _sds(one_chip, (4, 2048, 2, 128), jnp.bfloat16)
+    text = _compiled_text(functools.partial(flash_mha, causal=True), q, kv, kv)
+    assert "tpu_custom_call" in text
+
+
+def test_rmsnorm_compiles_qwen2_width(one_chip):
+    from repro.kernels.rmsnorm.kernel import fused_rmsnorm
+
+    x = _sds(one_chip, (8192, 1536), jnp.bfloat16)
+    w = _sds(one_chip, (1536,), jnp.bfloat16)
+    assert "tpu_custom_call" in _compiled_text(fused_rmsnorm, x, w)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssm_scan_compiles_hymba_width(one_chip, dtype):
+    from repro.configs import REGISTRY
+    from repro.kernels.ssm_scan.ops import ssm_scan_batched
+
+    cfg = REGISTRY["hymba-1.5b"]
+    inner = cfg.ssm_expand * cfg.d_model            # 3200
+    a = _sds(one_chip, (4, 2048, inner), dtype)
+    assert "tpu_custom_call" in _compiled_text(ssm_scan_batched, a, a)
+
+
+def test_prescreen_compiles_lm_full_pod_body(one_chip):
+    from repro.core.vectorized import (_schedule_many_stats_impl,
+                                       from_tasks, params_of)
+    from repro.graph.compiler import CompileOptions, compile_ops
+    from repro.graph.workloads import model_parts
+    from repro.sweep.spec import load_spec
+
+    spec = load_spec("lm_full_pod")
+    cell = spec.cells()[0]
+    parts = model_parts(cell.workload)
+    cw = compile_ops(parts.body(), cell.base_cfg(),
+                     CompileOptions(n_tiles=cell.n_tiles, **spec.compile_opts))
+    arrays = from_tasks(cw.tasks)
+    pm = np.stack([params_of(p.cfg(spec)) for p in cell.points])
+    args = jax.tree_util.tree_map(
+        lambda x: _sds(one_chip, np.shape(x), jnp.asarray(x).dtype),
+        (arrays, pm))
+    compiled = _schedule_many_stats_impl.lower(*args, repeats=1).compile()
+    mk, busy = compiled.out_info
+    assert mk.shape == (len(cell.points),)
+    assert busy.shape == (len(cell.points), 4)
