@@ -249,37 +249,3 @@ def pti_bins(sa: SampleArrays, module_ids: Iterable[int], kind: str,
                        np.where(hi > lo, rate[row] * (hi - lo), 0.0))
     np.add.at(bins, b, contrib)
     return bins
-
-
-def to_chrome_trace(tracer: "Tracer") -> dict:
-    """Export the activity + task timeline as a Chrome/Perfetto trace
-    (chrome://tracing 'traceEvents' JSON). Engines become pids/tids;
-    task-level records and sub-task activity samples become complete
-    events — load the file in Perfetto to see the paper's Fig-8-style
-    pipeline/concurrency picture interactively."""
-    events = []
-    pids = {}
-
-    def pid_of(module: str) -> int:
-        root = module.split(".")[0]
-        if root not in pids:
-            pids[root] = len(pids) + 1
-            events.append({"ph": "M", "pid": pids[root], "name":
-                           "process_name", "args": {"name": root}})
-        return pids[root]
-
-    for rec in tracer.tasks:
-        events.append({
-            "ph": "X", "name": rec.task, "cat": "task",
-            "pid": pid_of(rec.engine), "tid": rec.engine,
-            "ts": rec.t_start / 1e3,              # us
-            "dur": max(rec.t_end - rec.t_start, 1e-3) / 1e3,
-            "args": {"queued_us": (rec.t_start - rec.t_enqueue) / 1e3},
-        })
-    for s in tracer.samples:
-        events.append({
-            "ph": "X", "name": f"{s.kind}={s.amount:.3g}", "cat": "activity",
-            "pid": pid_of(s.module), "tid": s.module,
-            "ts": s.t0 / 1e3, "dur": max(s.duration, 1e-3) / 1e3,
-        })
-    return {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -13,6 +13,10 @@ serving paths use:
 Blocks are assembled into models by ``model.py`` as *segments* (scanned
 stacks of identical blocks, or single unrolled blocks where the arch is
 non-uniform: Hymba's 3 global-attention layers, xLSTM's sLSTM positions).
+
+Each part of a block runs under a ``jax.named_scope`` from
+``scopes.SCOPES`` (``attn``, ``kv_write``, ``mlp``, ``moe``, ``ssm``,
+``cross_attn``), so a profiler trace names it; scopes are metadata only.
 """
 from __future__ import annotations
 
@@ -137,38 +141,45 @@ def _qkv(cfg: ArchConfig, p, h, rope):
     return q, k, v
 
 
+def _ffn_scope(cfg: ArchConfig):
+    return jax.named_scope("moe" if cfg.is_moe else "mlp")
+
+
 def _ffn(cfg: ArchConfig, p, x, res):
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    h2 = constrain(h2, "batch", "act_seq", None)
-    if cfg.is_moe:
-        f = moe_ffn(
-            h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-            k=cfg.experts_per_token, n_experts=cfg.n_experts,
-            capacity_factor=cfg.capacity_factor,
-        )
-    else:
-        f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-    x = x + f * res
-    return constrain(x, "batch", "act_seq", None)
+    with _ffn_scope(cfg):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        h2 = constrain(h2, "batch", "act_seq", None)
+        if cfg.is_moe:
+            f = moe_ffn(
+                h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+                k=cfg.experts_per_token, n_experts=cfg.n_experts,
+                capacity_factor=cfg.capacity_factor,
+            )
+        else:
+            f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
+        x = x + f * res
+        return constrain(x, "batch", "act_seq", None)
 
 
 def _attn_apply(cfg: ArchConfig, p, x, ctx: BlockCtx) -> jax.Array:
     res = _res_scale(cfg)
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    h = constrain(h, "batch", "act_seq", None)
-    q, k, v = _qkv(cfg, p, h, ctx.rope)
-    if ctx.window > 0 and ctx.n_sink > 0:
-        o = sink_banded_attention(q, k, v, window=ctx.window,
-                                  n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-    else:
-        o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
-                      q_chunk=ctx.q_chunk)
-    # named for selective remat: policy 'save-attn' keeps this [B,S,H,hd]
-    # tensor so backward never re-runs the O(S^2) score pipeline
-    from jax.ad_checkpoint import checkpoint_name
-    o = checkpoint_name(o, "attn_out")
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = x + o * res
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = constrain(h, "batch", "act_seq", None)
+        q, k, v = _qkv(cfg, p, h, ctx.rope)
+        if ctx.window > 0 and ctx.n_sink > 0:
+            o = sink_banded_attention(q, k, v, window=ctx.window,
+                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
+        else:
+            o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
+                          q_chunk=ctx.q_chunk)
+        # named for selective remat: policy 'save-attn' keeps this
+        # [B,S,H,hd] tensor so backward never re-runs the O(S^2) score
+        # pipeline
+        from jax.ad_checkpoint import checkpoint_name
+        o = checkpoint_name(o, "attn_out")
+        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + o * res
     return _ffn(cfg, p, x, res)
 
 
@@ -189,23 +200,26 @@ def _attn_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx):
     """Apply + build the cache slice from this layer's K/V."""
     res = _res_scale(cfg)
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    h = constrain(h, "batch", "act_seq", None)
-    q, k, v = _qkv(cfg, p, h, ctx.rope)
-    if ctx.window > 0 and ctx.n_sink > 0:
-        o = sink_banded_attention(q, k, v, window=ctx.window,
-                                  n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-    else:
-        o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
-                      q_chunk=ctx.q_chunk)
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = _ffn(cfg, p, x + o * res, res)
-    _, cache = _pack_attn_cache(cfg, k, v, ctx)
-    seq_ax = "kv_seq" if ctx.window == 0 else None
-    cache = {
-        "k": constrain(cache["k"], "batch", seq_ax, "kv_heads", None),
-        "v": constrain(cache["v"], "batch", seq_ax, "kv_heads", None),
-    }
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = constrain(h, "batch", "act_seq", None)
+        q, k, v = _qkv(cfg, p, h, ctx.rope)
+        if ctx.window > 0 and ctx.n_sink > 0:
+            o = sink_banded_attention(q, k, v, window=ctx.window,
+                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
+        else:
+            o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
+                          q_chunk=ctx.q_chunk)
+        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + o * res
+    x = _ffn(cfg, p, x, res)
+    with jax.named_scope("kv_write"):
+        _, cache = _pack_attn_cache(cfg, k, v, ctx)
+        seq_ax = "kv_seq" if ctx.window == 0 else None
+        cache = {
+            "k": constrain(cache["k"], "batch", seq_ax, "kv_heads", None),
+            "v": constrain(cache["v"], "batch", seq_ax, "kv_heads", None),
+        }
     return x, cache
 
 
@@ -213,12 +227,27 @@ def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx):
     """x [B,1,d]; cache {k,v [B,W,KV,hd]}; ctx.pos = absolute position."""
     res = _res_scale(cfg)
     pos = ctx.pos
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    rope = rope_at(pos, cfg.hd, cfg.rope_theta) if ctx.rope is not None else None
-    q, k, v = _qkv(cfg, p, h, rope)
-    # decode shards the CACHE over 'model' (flash-decoding); q must keep
-    # heads replicated or GSPMD all-gathers the cache slice every layer
-    q = constrain(q, "batch", None, None, None)
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        rope = (rope_at(pos, cfg.hd, cfg.rope_theta)
+                if ctx.rope is not None else None)
+        q, k, v = _qkv(cfg, p, h, rope)
+        # decode shards the CACHE over 'model' (flash-decoding); q must
+        # keep heads replicated or GSPMD all-gathers the cache slice every
+        # layer
+        q = constrain(q, "batch", None, None, None)
+        ck, cv, valid = _write_kv(cache, k, v, pos, ctx)
+        o = decode_attention(q, ck, cv, valid)
+        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + o * res
+    x = _ffn_decode(cfg, p, x, res)
+    return x, {"k": ck, "v": cv}
+
+
+def _write_kv(cache, k, v, pos, ctx: BlockCtx):
+    """Write one position's k/v [B,1,KV,hd] into the cache at its slot
+    (ring slots past the sink for windowed layers); returns the new cache
+    arrays and the mask of valid slots."""
     W = cache["k"].shape[1]
     if ctx.window == 0:
         slot = pos
@@ -227,26 +256,24 @@ def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx):
         ns = ctx.n_sink
         slot = jnp.where(pos < ns, pos, ns + (pos - ns) % ctx.window)
         valid = (jnp.arange(W) <= pos) | (pos >= W)
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
-    o = decode_attention(q, ck, cv, valid)
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = x + o * res
-    x = _ffn_decode(cfg, p, x, res)
-    return x, {"k": ck, "v": cv}
+    with jax.named_scope("kv_write"):
+        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
+        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
+    return ck, cv, valid
 
 
 def _ffn_decode(cfg: ArchConfig, p, x, res):
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
-        f = moe_ffn(
-            h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-            k=cfg.experts_per_token, n_experts=cfg.n_experts,
-            capacity_factor=cfg.capacity_factor,
-        )
-    else:
-        f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-    return x + f * res
+    with _ffn_scope(cfg):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if cfg.is_moe:
+            f = moe_ffn(
+                h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+                k=cfg.experts_per_token, n_experts=cfg.n_experts,
+                capacity_factor=cfg.capacity_factor,
+            )
+        else:
+            f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
+        return x + f * res
 
 
 ATTN_BLOCK = Block(
@@ -283,24 +310,27 @@ def _cross_template(cfg: ArchConfig) -> Dict[str, Any]:
 
 
 def _img_kv(p, img, eps):
-    k = jnp.einsum("bid,dkh->bikh", img, p["wk"])
-    v = jnp.einsum("bid,dkh->bikh", img, p["wv"])
-    k = rms_norm(k, p["k_norm"], eps)
-    return k, v
+    with jax.named_scope("cross_attn"):
+        k = jnp.einsum("bid,dkh->bikh", img, p["wk"])
+        v = jnp.einsum("bid,dkh->bikh", img, p["wv"])
+        k = rms_norm(k, p["k_norm"], eps)
+        return k, v
 
 
 def _cross_core(cfg, p, x, k_img, v_img):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
-    q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-    q = constrain(q, "batch", "act_seq", "heads", None)
-    o = cross_attention(q, k_img, v_img)
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = x + jnp.tanh(p["gate_attn"]) * o
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-    x = x + jnp.tanh(p["gate_ffn"]) * f
-    return constrain(x, "batch", "act_seq", None)
+    with jax.named_scope("cross_attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        q = constrain(q, "batch", "act_seq", "heads", None)
+        o = cross_attention(q, k_img, v_img)
+        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + jnp.tanh(p["gate_attn"]) * o
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
+        x = x + jnp.tanh(p["gate_ffn"]) * f
+        return constrain(x, "batch", "act_seq", None)
 
 
 def _cross_apply(cfg, p, x, ctx: BlockCtx):
@@ -373,49 +403,51 @@ def _hybrid_template(cfg: ArchConfig) -> Dict[str, Any]:
 
 
 def _hybrid_mamba(cfg, p, h, state=None, return_state=False, decode=False):
-    di = cfg.ssm_expand * cfg.d_model
-    xz = jnp.einsum("bsd,de->bse", h, p["w_in"])
-    xz = constrain(xz, "batch", None, "ssm_inner")
-    x_in, z = jnp.split(xz, 2, axis=-1)
-    kw = dict(n_state=cfg.ssm_state, dt_rank=_dt_rank(cfg))
-    if decode:
-        y, st = mamba_decode_mix(
-            x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-            p["a_log"], p["d_skip"], state=state, **kw)
-        out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
-        return out, st
-    if return_state:
-        y, st = mamba_mix(
-            x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-            p["a_log"], p["d_skip"], state=state, return_state=True, **kw)
-        out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
-        return out, st
-    y = mamba_mix(x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-                  p["a_log"], p["d_skip"], state=state, **kw)
-    return jnp.einsum("bsd,de->bse", y, p["wo_m"])
+    with jax.named_scope("ssm"):
+        xz = jnp.einsum("bsd,de->bse", h, p["w_in"])
+        xz = constrain(xz, "batch", None, "ssm_inner")
+        x_in, z = jnp.split(xz, 2, axis=-1)
+        kw = dict(n_state=cfg.ssm_state, dt_rank=_dt_rank(cfg))
+        if decode:
+            y, st = mamba_decode_mix(
+                x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
+                p["a_log"], p["d_skip"], state=state, **kw)
+            out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
+            return out, st
+        if return_state:
+            y, st = mamba_mix(
+                x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
+                p["a_log"], p["d_skip"], state=state, return_state=True, **kw)
+            out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
+            return out, st
+        y = mamba_mix(x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
+                      p["a_log"], p["d_skip"], state=state, **kw)
+        return jnp.einsum("bsd,de->bse", y, p["wo_m"])
 
 
 def _hybrid_fuse(cfg, p, x, o_attn, o_ssm):
-    fused = 0.5 * (rms_norm(o_attn, p["norm_attn"], cfg.norm_eps)
-                   + rms_norm(o_ssm, p["norm_ssm"], cfg.norm_eps))
-    x = x + fused
-    x = constrain(x, "batch", "act_seq", None)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-    return constrain(x + f, "batch", "act_seq", None)
+    with jax.named_scope("mlp"):
+        fused = 0.5 * (rms_norm(o_attn, p["norm_attn"], cfg.norm_eps)
+                       + rms_norm(o_ssm, p["norm_ssm"], cfg.norm_eps))
+        x = x + fused
+        x = constrain(x, "batch", "act_seq", None)
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
+        return constrain(x + f, "batch", "act_seq", None)
 
 
 def _hybrid_apply(cfg, p, x, ctx: BlockCtx):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    h = constrain(h, "batch", "act_seq", None)
-    q, k, v = _qkv(cfg, p, h, ctx.rope)
-    if ctx.window > 0 and ctx.n_sink > 0:
-        o = sink_banded_attention(q, k, v, window=ctx.window,
-                                  n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-    else:
-        o = attention(q, k, v, causal=True, window=ctx.window,
-                      q_chunk=ctx.q_chunk)
-    o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = constrain(h, "batch", "act_seq", None)
+        q, k, v = _qkv(cfg, p, h, ctx.rope)
+        if ctx.window > 0 and ctx.n_sink > 0:
+            o = sink_banded_attention(q, k, v, window=ctx.window,
+                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
+        else:
+            o = attention(q, k, v, causal=True, window=ctx.window,
+                          q_chunk=ctx.q_chunk)
+        o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     o_ssm = _hybrid_mamba(cfg, p, h)
     return _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
 
@@ -431,24 +463,27 @@ def _hybrid_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 
 
 def _hybrid_prefill(cfg, p, x, ctx: BlockCtx):
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    h = constrain(h, "batch", "act_seq", None)
-    q, k, v = _qkv(cfg, p, h, ctx.rope)
-    if ctx.window > 0 and ctx.n_sink > 0:
-        o = sink_banded_attention(q, k, v, window=ctx.window,
-                                  n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-    else:
-        o = attention(q, k, v, causal=True, window=ctx.window,
-                      q_chunk=ctx.q_chunk)
-    o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        h = constrain(h, "batch", "act_seq", None)
+        q, k, v = _qkv(cfg, p, h, ctx.rope)
+        if ctx.window > 0 and ctx.n_sink > 0:
+            o = sink_banded_attention(q, k, v, window=ctx.window,
+                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
+        else:
+            o = attention(q, k, v, causal=True, window=ctx.window,
+                          q_chunk=ctx.q_chunk)
+        o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     o_ssm, st = _hybrid_mamba(cfg, p, h, return_state=True)
     xo = _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
 
     # attention cache (same ring layout as ATTN_BLOCK.prefill)
-    _, attn_cache = _pack_attn_cache(cfg, k, v, ctx)
+    with jax.named_scope("kv_write"):
+        _, attn_cache = _pack_attn_cache(cfg, k, v, ctx)
     cache = dict(attn_cache)
-    cache["conv"] = st.conv.astype(jnp.float32)
-    cache["ssm"] = st.ssm.astype(jnp.float32)
+    with jax.named_scope("ssm"):
+        cache["conv"] = st.conv.astype(jnp.float32)
+        cache["ssm"] = st.ssm.astype(jnp.float32)
     return xo, cache
 
 
@@ -476,22 +511,15 @@ def _pack_attn_cache(cfg, k, v, ctx: BlockCtx):
 
 def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx):
     pos = ctx.pos
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    rope = rope_at(pos, cfg.hd, cfg.rope_theta) if ctx.rope is not None else None
-    q, k, v = _qkv(cfg, p, h, rope)
-    q = constrain(q, "batch", None, None, None)
-    W = cache["k"].shape[1]
-    if ctx.window == 0:
-        slot = pos
-        valid = jnp.arange(W) <= pos
-    else:
-        ns = ctx.n_sink
-        slot = jnp.where(pos < ns, pos, ns + (pos - ns) % ctx.window)
-        valid = (jnp.arange(W) <= pos) | (pos >= W)
-    ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
-    o = decode_attention(q, ck, cv, valid)
-    o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    with jax.named_scope("attn"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        rope = (rope_at(pos, cfg.hd, cfg.rope_theta)
+                if ctx.rope is not None else None)
+        q, k, v = _qkv(cfg, p, h, rope)
+        q = constrain(q, "batch", None, None, None)
+        ck, cv, valid = _write_kv(cache, k, v, pos, ctx)
+        o = decode_attention(q, ck, cv, valid)
+        o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
     st = MambaState(conv=cache["conv"], ssm=cache["ssm"])
     o_ssm, st = _hybrid_mamba(cfg, p, h, state=st, decode=True)
     xo = _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
@@ -547,9 +575,10 @@ def _mlstm_out(cfg, p, x, hc, z):
 
 
 def _mlstm_apply(cfg, p, x, ctx: BlockCtx):
-    q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
-    hc = mlstm_chunked(q, k, v, ig, fg)
-    return _mlstm_out(cfg, p, x, hc, z)
+    with jax.named_scope("ssm"):
+        q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
+        hc = mlstm_chunked(q, k, v, ig, fg)
+        return _mlstm_out(cfg, p, x, hc, z)
 
 
 def _mlstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
@@ -564,17 +593,19 @@ def _mlstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 
 
 def _mlstm_prefill(cfg, p, x, ctx: BlockCtx):
-    q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
-    hc, (C, n, m) = mlstm_chunked(q, k, v, ig, fg, return_state=True)
-    return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
+    with jax.named_scope("ssm"):
+        q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
+        hc, (C, n, m) = mlstm_chunked(q, k, v, ig, fg, return_state=True)
+        return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
 
 
 def _mlstm_decode(cfg, p, x, cache, ctx: BlockCtx):
-    q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
-    hc, (C, n, m) = mlstm_decode_step(
-        q, k, v, ig, fg, (cache["C"], cache["n"], cache["m"])
-    )
-    return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
+    with jax.named_scope("ssm"):
+        q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
+        hc, (C, n, m) = mlstm_decode_step(
+            q, k, v, ig, fg, (cache["C"], cache["n"], cache["m"])
+        )
+        return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
 
 
 MLSTM_BLOCK = Block(
@@ -615,17 +646,21 @@ def _slstm_gates(cfg, p, x):
 
 
 def _slstm_post(cfg, p, x, hs):
-    B, S = x.shape[0], x.shape[1]
-    hc = rms_norm(hs.reshape(B, S, cfg.d_model), p["norm_cell"], cfg.norm_eps)
-    x = x + jnp.einsum("bsd,de->bse", hc, p["wo"])
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-    return constrain(x + f, "batch", "act_seq", None)
+    with jax.named_scope("ssm"):
+        B, S = x.shape[0], x.shape[1]
+        hc = rms_norm(hs.reshape(B, S, cfg.d_model), p["norm_cell"],
+                      cfg.norm_eps)
+        x = x + jnp.einsum("bsd,de->bse", hc, p["wo"])
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+        f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
+        return constrain(x + f, "batch", "act_seq", None)
 
 
 def _slstm_apply(cfg, p, x, ctx: BlockCtx):
-    gx = _slstm_gates(cfg, p, x)
-    hs, _ = slstm_scan(gx, p["r_gates"])
+    with jax.named_scope("ssm"):
+        gx = _slstm_gates(cfg, p, x)
+        hs, _ = slstm_scan(gx, p["r_gates"])
     return _slstm_post(cfg, p, x, hs)
 
 
@@ -639,16 +674,19 @@ def _slstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 
 
 def _slstm_prefill(cfg, p, x, ctx: BlockCtx):
-    gx = _slstm_gates(cfg, p, x)
-    hs, (c, n, h, m) = slstm_scan(gx, p["r_gates"])
+    with jax.named_scope("ssm"):
+        gx = _slstm_gates(cfg, p, x)
+        hs, (c, n, h, m) = slstm_scan(gx, p["r_gates"])
     return _slstm_post(cfg, p, x, hs), {"c": c, "n": n, "h": h, "m": m}
 
 
 def _slstm_decode(cfg, p, x, cache, ctx: BlockCtx):
-    gx = _slstm_gates(cfg, p, x)
-    hs, (c, n, h, m) = slstm_decode_step(
-        gx, p["r_gates"], (cache["c"], cache["n"], cache["h"], cache["m"])
-    )
+    with jax.named_scope("ssm"):
+        gx = _slstm_gates(cfg, p, x)
+        hs, (c, n, h, m) = slstm_decode_step(
+            gx, p["r_gates"], (cache["c"], cache["n"], cache["h"],
+                               cache["m"])
+        )
     return _slstm_post(cfg, p, x, hs), {"c": c, "n": n, "h": h, "m": m}
 
 

@@ -10,6 +10,9 @@ single unrolled block where the arch is non-uniform:
   hybrid (Hymba)        [SWA-hybrid runs] + [global-attn hybrid singles]
 
 The same block numerics serve train, prefill and decode (kv/ssm/cell cache).
+Each part runs under a ``jax.named_scope`` of ``scopes.SCOPES``: ``embed``,
+``layers`` (each segment's stack), ``final_norm`` and ``lm_head`` here, the
+block's parts in ``blocks.py``.
 """
 from __future__ import annotations
 
@@ -234,27 +237,28 @@ class Model:
 
     def _embed(self, params, batch):
         cfg = self.cfg
-        if cfg.family == "audio":
-            x = batch["frames"].astype(params["in_norm"].dtype)
-            x = rms_norm(x, params["in_norm"], cfg.norm_eps)
-            # fixed sinusoidal positions (frontend stub has none)
-            S, d = x.shape[1], x.shape[2]
-            pos = jnp.arange(S, dtype=jnp.float32)[:, None]
-            div = jnp.exp(jnp.arange(0, d, 2, jnp.float32) * (-math.log(1e4) / d))
-            pe = jnp.zeros((S, d), jnp.float32)
-            pe = pe.at[:, 0::2].set(jnp.sin(pos * div))
-            pe = pe.at[:, 1::2].set(jnp.cos(pos * div))
-            x = x + pe.astype(x.dtype)[None]
-        else:
-            x = jnp.take(params["embed"], batch["tokens"], axis=0)
-            if cfg.scale_emb != 1.0:
-                x = x * cfg.scale_emb
-        if cfg.n_meta_tokens:
-            B = x.shape[0]
-            meta = jnp.broadcast_to(params["meta"][None],
-                                    (B,) + params["meta"].shape)
-            x = jnp.concatenate([meta.astype(x.dtype), x], axis=1)
-        return constrain(x, "batch", "act_seq", None)
+        with jax.named_scope("embed"):
+            if cfg.family == "audio":
+                x = batch["frames"].astype(params["in_norm"].dtype)
+                x = rms_norm(x, params["in_norm"], cfg.norm_eps)
+                # fixed sinusoidal positions (frontend stub has none)
+                S, d = x.shape[1], x.shape[2]
+                pos = jnp.arange(S, dtype=jnp.float32)[:, None]
+                div = jnp.exp(jnp.arange(0, d, 2, jnp.float32) * (-math.log(1e4) / d))
+                pe = jnp.zeros((S, d), jnp.float32)
+                pe = pe.at[:, 0::2].set(jnp.sin(pos * div))
+                pe = pe.at[:, 1::2].set(jnp.cos(pos * div))
+                x = x + pe.astype(x.dtype)[None]
+            else:
+                x = jnp.take(params["embed"], batch["tokens"], axis=0)
+                if cfg.scale_emb != 1.0:
+                    x = x * cfg.scale_emb
+            if cfg.n_meta_tokens:
+                B = x.shape[0]
+                meta = jnp.broadcast_to(params["meta"][None],
+                                        (B,) + params["meta"].shape)
+                x = jnp.concatenate([meta.astype(x.dtype), x], axis=1)
+            return constrain(x, "batch", "act_seq", None)
 
     def _rope_for(self, S: int):
         if not _uses_rope(self.cfg):
@@ -282,8 +286,10 @@ class Model:
             img = img.astype(x.dtype)
         for seg, p in zip(self.segments, params["segments"]):
             ctx = self._ctx(seg, rope=rope, img=img)
-            x = self._apply_segment(seg, p, x, ctx, remat=for_train)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps)
+            with jax.named_scope("layers"):
+                x = self._apply_segment(seg, p, x, ctx, remat=for_train)
+        with jax.named_scope("final_norm"):
+            return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
     def _apply_segment(self, seg: Segment, p, x, ctx: BlockCtx, remat: bool):
         cfg = self.cfg
@@ -320,25 +326,31 @@ class Model:
         h = self.forward(params, batch, for_train=True)
         if cfg.n_meta_tokens:
             h = h[:, cfg.n_meta_tokens:]
-        head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        scale = (1.0 / (cfg.d_model / cfg.dim_model_base)
-                 if cfg.dim_model_base else 1.0)
-        return cross_entropy_chunked(h, head, batch["labels"],
-                                     logit_scale=scale,
-                                     n_chunks=self.ce_chunks)
+        with jax.named_scope("lm_head"):
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["head"])
+            scale = (1.0 / (cfg.d_model / cfg.dim_model_base)
+                     if cfg.dim_model_base else 1.0)
+            return cross_entropy_chunked(h, head, batch["labels"],
+                                         logit_scale=scale,
+                                         n_chunks=self.ce_chunks)
 
     # -- serving ----------------------------------------------------------
     def _logits(self, params, h_last: jax.Array) -> jax.Array:
         cfg = self.cfg
-        head = params["embed"].T if cfg.tie_embeddings else params["head"]
-        scale = (1.0 / (cfg.d_model / cfg.dim_model_base)
-                 if cfg.dim_model_base else 1.0)
-        logits = jnp.einsum("bd,dv->bv", h_last, head).astype(jnp.float32)
-        # keep logits vocab-sharded: without this constraint GSPMD chooses
-        # to all-gather the (d x V) head in f32 per decode step (~200MB for
-        # 150k vocabs) — found via TPU-EM replay of the compiled program
-        logits = constrain(logits, "batch", "vocab")
-        return logits * scale
+        with jax.named_scope("lm_head"):
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["head"])
+            scale = (1.0 / (cfg.d_model / cfg.dim_model_base)
+                     if cfg.dim_model_base else 1.0)
+            logits = jnp.einsum("bd,dv->bv", h_last,
+                                head).astype(jnp.float32)
+            # keep logits vocab-sharded: without this constraint GSPMD
+            # chooses to all-gather the (d x V) head in f32 per decode step
+            # (~200MB for 150k vocabs) — found via TPU-EM replay of the
+            # compiled program
+            logits = constrain(logits, "batch", "vocab")
+            return logits * scale
 
     def prefill(self, params, batch, smax: int):
         """Process the prompt; returns (last-token logits [B,V], cache)."""
@@ -353,10 +365,12 @@ class Model:
         caches = []
         for seg, p in zip(self.segments, params["segments"]):
             ctx = self._ctx(seg, rope=rope, img=img, smax=smax_tot)
-            x, c = self._prefill_segment(seg, p, x, ctx)
+            with jax.named_scope("layers"):
+                x, c = self._prefill_segment(seg, p, x, ctx)
             caches.append(c)
-        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = self._logits(params, h[:, -1])
+        with jax.named_scope("final_norm"):
+            h = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, -1]
+        logits = self._logits(params, h)
         # pos counts REAL sequence tokens (meta prefix excluded); decode adds
         # the meta offset back when computing absolute cache slots.
         cache = {"pos": jnp.asarray(S - cfg.n_meta_tokens, jnp.int32),
@@ -389,19 +403,22 @@ class Model:
         """One decode step. tokens [B,1] -> (logits [B,V], new cache)."""
         cfg = self.cfg
         pos = cache["pos"] + cfg.n_meta_tokens  # absolute slot incl. meta
-        x = jnp.take(params["embed"], tokens, axis=0)
-        if cfg.scale_emb != 1.0:
-            x = x * cfg.scale_emb
-        x = constrain(x, "batch", None, None)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0)
+            if cfg.scale_emb != 1.0:
+                x = x * cfg.scale_emb
+            x = constrain(x, "batch", None, None)
         rope_flag = self._rope_for(1)  # non-None => blocks compute rope_at(pos)
         new_caches = []
         for seg, p, c in zip(self.segments, params["segments"],
                              cache["segments"]):
             ctx = self._ctx(seg, rope=rope_flag, pos=pos)
-            x, nc = self._decode_segment(seg, p, x, c, ctx)
+            with jax.named_scope("layers"):
+                x, nc = self._decode_segment(seg, p, x, c, ctx)
             new_caches.append(nc)
-        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = self._logits(params, h[:, 0])
+        with jax.named_scope("final_norm"):
+            h = rms_norm(x, params["final_norm"], cfg.norm_eps)[:, 0]
+        logits = self._logits(params, h)
         return logits, {"pos": cache["pos"] + 1, "segments": new_caches}
 
     def _decode_segment(self, seg: Segment, p, x, c, ctx: BlockCtx):

@@ -1,7 +1,6 @@
 """Perfetto/Chrome-trace exporter: every simulation plane as a timeline.
 
-Generalizes ``core.trace.to_chrome_trace`` (one tracer's task/activity
-events) into three track families over the whole stack, all emitted in
+Three track families over the whole stack, all emitted in
 'traceEvents' JSON that chrome://tracing and ui.perfetto.dev load
 directly:
 
@@ -85,8 +84,8 @@ class TraceBuilder:
 
 
 def _tracer_events(tb: TraceBuilder, tracer) -> None:
-    """The ``core.trace.to_chrome_trace`` family: task timeline spans
-    (engines as threads of their root module) + activity samples."""
+    """One tracer's task timeline spans (engines as threads of their
+    root module) + activity samples."""
     for rec in tracer.tasks:
         tb.span(rec.engine.split(".")[0], rec.engine, rec.task,
                 ts_us=rec.t_start / 1e3,

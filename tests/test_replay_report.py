@@ -6,7 +6,6 @@ import pytest
 
 from benchmarks.roofline import model_flops
 from repro.configs import REGISTRY, SHAPES
-from repro.core.trace import to_chrome_trace
 from repro.graph.hlo_parser import Collective, TaskSpec
 from repro.graph.stackem import _clone_tasks
 from repro.graph.tasks import Task
@@ -70,10 +69,13 @@ def test_model_flops_swa_discount():
 def test_chrome_trace_export():
     tasks = [Task("tile0.mxu", GemmSpec(m=256, n=256, k=256), name="mm")]
     from repro.hw.chip import System
+    from repro.obs.perfetto import TraceBuilder, _tracer_events
 
     sysm = System(V5E)
     sysm.run_workload(tasks)
-    trace = to_chrome_trace(sysm.tracer)
+    tb = TraceBuilder()
+    _tracer_events(tb, sysm.tracer)
+    trace = tb.trace()
     assert any(e.get("name") == "mm" for e in trace["traceEvents"])
     json.dumps(trace)  # serializable
 
