@@ -13,7 +13,7 @@ Three code paths:
     flash-decoding collective pattern (small all-reduces), never an
     all-gather of the cache.
 
-Shapes: q [B,S,H,hd], k/v [B,Skv,KV,hd], cache k/v [B,Smax,KV,hd].
+Shapes: q [B,S,H,hd], k/v [B,Skv,KV,hd], cache k/v [B,KV,Smax,hd].
 """
 from __future__ import annotations
 
@@ -186,7 +186,7 @@ def decode_attention(
     *,
     scale: Optional[float] = None,
 ) -> jax.Array:
-    """One-token decode: q [B,1,H,hd] vs cache [B,Smax,KV,hd] (kv_seq-sharded).
+    """One-token decode: q [B,1,H,hd] vs cache [B,KV,Smax,hd] (kv_seq-sharded).
 
     ``valid`` [Smax] bool marks live cache slots (caller encodes causal /
     ring-buffer semantics). Softmax + PV reduce over the sharded Smax dim ->
@@ -194,12 +194,12 @@ def decode_attention(
     all-gather of the cache.
     """
     B, _, H, hd = q.shape
-    Smax, KV = k_cache.shape[1], k_cache.shape[2]
+    KV = k_cache.shape[1]
     G = H // KV
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
     qg = q.reshape(B, 1, KV, G, hd)
-    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k_cache).astype(jnp.float32) * scale
+    scores = jnp.einsum("bqkgh,bksh->bkgqs", qg, k_cache).astype(jnp.float32) * scale
     scores = jnp.where(valid[None, None, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
-    out = jnp.einsum("bkgqs,bskh->bqkgh", probs, v_cache)
+    out = jnp.einsum("bkgqs,bksh->bqkgh", probs, v_cache)
     return out.reshape(B, 1, H, hd)

@@ -7,8 +7,16 @@ serving paths use:
   template(cfg)                   -> pytree of PT
   apply(cfg, p, x, ctx)           -> x                     (train / no-cache)
   prefill(cfg, p, x, ctx)         -> (x, cache_slice)
-  decode(cfg, p, x, cache, ctx)   -> (x, new_cache_slice)
+  decode(cfg, p, x, cache, ctx, at=()) -> (x, new_cache)
   cache_template(cfg, B, ctx)     -> pytree of PT (cache shapes/axes/dtypes)
+
+In decode, ``at`` holds the layer's indices into a cache stacked over
+layers (empty: ``cache`` is the layer's own). A scanned segment carries
+its whole stacked cache through the scan, and each block updates its own
+layer in place, by the kind of its state: attention writes the new
+position's k/v into its slot of layer ``at`` and reads its layer's view of
+the result; recurrent states (mLSTM, sLSTM, Mamba) are written back whole
+at ``at``; image k/v are only read.
 
 Blocks are assembled into models by ``model.py`` as *segments* (scanned
 stacks of identical blocks, or single unrolled blocks where the arch is
@@ -82,6 +90,30 @@ def rope_at(pos: jax.Array, head_dim: int, theta: float):
     freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
     ang = pos.astype(jnp.float32) * freqs
     return jnp.cos(ang)[None], jnp.sin(ang)[None]
+
+
+def _layer_of(a: jax.Array, at: Tuple[jax.Array, ...]) -> jax.Array:
+    """The layer's view of a cache array stacked over the indices ``at``."""
+    n = len(at)
+    start = tuple(at) + (0,) * (a.ndim - n)
+    return jax.lax.dynamic_slice(a, start, (1,) * n + a.shape[n:]).reshape(
+        a.shape[n:])
+
+
+def _put_at(a: jax.Array, new: jax.Array, at: Tuple[jax.Array, ...],
+            *offsets) -> jax.Array:
+    """Write ``new`` into layer ``at`` of the stacked array ``a``, at
+    ``offsets`` within the layer (zero where not given)."""
+    n = len(at)
+    start = tuple(at) + offsets + (0,) * (a.ndim - n - len(offsets))
+    return jax.lax.dynamic_update_slice(
+        a, new.astype(a.dtype).reshape((1,) * n + new.shape), start)
+
+
+def _store(cache, new, at: Tuple[jax.Array, ...]):
+    """Write a layer's whole new recurrent state back at ``at``, in the
+    cache's dtype."""
+    return {k: _put_at(cache[k], v, at) for k, v in new.items()}
 
 
 def _res_scale(cfg: ArchConfig) -> float:
@@ -189,11 +221,17 @@ def _attn_cache_len(cfg: ArchConfig, ctx: BlockCtx) -> int:
     return ctx.smax
 
 
+def _kv_axes(ctx: BlockCtx):
+    """Logical axes of a layer's k/v cache [B, KV, W, hd]: each head's
+    slots are one [W, hd] block, the operand layout of decode's score and
+    PV contractions."""
+    return ("batch", "kv_heads", "kv_seq" if ctx.window == 0 else None, None)
+
+
 def _attn_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
     KV, hd = cfg.n_kv_heads, cfg.hd
     W = _attn_cache_len(cfg, ctx)
-    seq_ax = "kv_seq" if ctx.window == 0 else None
-    spec = PT((B, W, KV, hd), ("batch", seq_ax, "kv_heads", None), init="zeros")
+    spec = PT((B, KV, W, hd), _kv_axes(ctx), init="zeros")
     return {"k": spec, "v": spec}
 
 
@@ -215,16 +253,13 @@ def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx):
     x = _ffn(cfg, p, x, res)
     with jax.named_scope("kv_write"):
         _, cache = _pack_attn_cache(cfg, k, v, ctx)
-        seq_ax = "kv_seq" if ctx.window == 0 else None
-        cache = {
-            "k": constrain(cache["k"], "batch", seq_ax, "kv_heads", None),
-            "v": constrain(cache["v"], "batch", seq_ax, "kv_heads", None),
-        }
+        cache = {n: constrain(a, *_kv_axes(ctx)) for n, a in cache.items()}
     return x, cache
 
 
-def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx):
-    """x [B,1,d]; cache {k,v [B,W,KV,hd]}; ctx.pos = absolute position."""
+def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx, at=()):
+    """x [B,1,d]; cache {k,v [*stack,B,KV,W,hd]}; ctx.pos = absolute
+    position."""
     res = _res_scale(cfg)
     pos = ctx.pos
     with jax.named_scope("attn"):
@@ -236,19 +271,19 @@ def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx):
         # keep heads replicated or GSPMD all-gathers the cache slice every
         # layer
         q = constrain(q, "batch", None, None, None)
-        ck, cv, valid = _write_kv(cache, k, v, pos, ctx)
-        o = decode_attention(q, ck, cv, valid)
+        ck, cv, valid = _write_kv(cache, k, v, pos, ctx, at)
+        o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
         o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         x = x + o * res
     x = _ffn_decode(cfg, p, x, res)
     return x, {"k": ck, "v": cv}
 
 
-def _write_kv(cache, k, v, pos, ctx: BlockCtx):
-    """Write one position's k/v [B,1,KV,hd] into the cache at its slot
-    (ring slots past the sink for windowed layers); returns the new cache
-    arrays and the mask of valid slots."""
-    W = cache["k"].shape[1]
+def _write_kv(cache, k, v, pos, ctx: BlockCtx, at=()):
+    """Write one position's k/v [B,1,KV,hd] into layer ``at`` of the cache
+    at its slot (ring slots past the sink for windowed layers), in place;
+    returns the new cache arrays and the mask of valid slots."""
+    W = cache["k"].shape[len(at) + 2]
     if ctx.window == 0:
         slot = pos
         valid = jnp.arange(W) <= pos
@@ -256,9 +291,17 @@ def _write_kv(cache, k, v, pos, ctx: BlockCtx):
         ns = ctx.n_sink
         slot = jnp.where(pos < ns, pos, ns + (pos - ns) % ctx.window)
         valid = (jnp.arange(W) <= pos) | (pos >= W)
+    axes = ("stack",) * len(at) + _kv_axes(ctx)
     with jax.named_scope("kv_write"):
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
+        # [B,1,KV,hd] -> [B,KV,1,hd], through a flat barrier: the compiler
+        # then lays this small update out like the cache, instead of laying
+        # a carried cache out like the projection that made the update
+        # (which costs two copies of the whole cache per step)
+        B, _, KV, hd = k.shape
+        k, v = (a.reshape(B, KV, 1, hd) for a in
+                jax.lax.optimization_barrier((k.reshape(-1), v.reshape(-1))))
+        ck = constrain(_put_at(cache["k"], k, at, 0, 0, slot), *axes)
+        cv = constrain(_put_at(cache["v"], v, at, 0, 0, slot), *axes)
     return ck, cv, valid
 
 
@@ -343,8 +386,11 @@ def _cross_prefill(cfg, p, x, ctx: BlockCtx):
     return _cross_core(cfg, p, x, k_img, v_img), {"k": k_img, "v": v_img}
 
 
-def _cross_decode(cfg, p, x, cache, ctx: BlockCtx):
-    return _cross_core(cfg, p, x, cache["k"], cache["v"]), cache
+def _cross_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
+    # the image k/v are read only: the stacked cache passes through whole
+    x = _cross_core(cfg, p, x, _layer_of(cache["k"], at),
+                    _layer_of(cache["v"], at))
+    return x, cache
 
 
 def _cross_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
@@ -506,10 +552,10 @@ def _pack_attn_cache(cfg, k, v, ctx: BlockCtx):
         idx = ns + (start + jnp.arange(tail)) % ctx.window
         ck = ck.at[:, idx].set(k[:, S - tail:])
         cv = cv.at[:, idx].set(v[:, S - tail:])
-    return None, {"k": ck, "v": cv}
+    return None, {"k": ck.swapaxes(1, 2), "v": cv.swapaxes(1, 2)}
 
 
-def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx):
+def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
     pos = ctx.pos
     with jax.named_scope("attn"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -517,13 +563,16 @@ def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx):
                 if ctx.rope is not None else None)
         q, k, v = _qkv(cfg, p, h, rope)
         q = constrain(q, "batch", None, None, None)
-        ck, cv, valid = _write_kv(cache, k, v, pos, ctx)
-        o = decode_attention(q, ck, cv, valid)
+        ck, cv, valid = _write_kv(cache, k, v, pos, ctx, at)
+        o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
         o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    st = MambaState(conv=cache["conv"], ssm=cache["ssm"])
+    st = MambaState(conv=_layer_of(cache["conv"], at),
+                    ssm=_layer_of(cache["ssm"], at))
     o_ssm, st = _hybrid_mamba(cfg, p, h, state=st, decode=True)
     xo = _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
-    return xo, {"k": ck, "v": cv, "conv": st.conv, "ssm": st.ssm}
+    with jax.named_scope("ssm"):
+        state = _store(cache, {"conv": st.conv, "ssm": st.ssm}, at)
+    return xo, {"k": ck, "v": cv, **state}
 
 
 HYBRID_BLOCK = Block(
@@ -599,13 +648,14 @@ def _mlstm_prefill(cfg, p, x, ctx: BlockCtx):
         return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
 
 
-def _mlstm_decode(cfg, p, x, cache, ctx: BlockCtx):
+def _mlstm_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
     with jax.named_scope("ssm"):
         q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
         hc, (C, n, m) = mlstm_decode_step(
-            q, k, v, ig, fg, (cache["C"], cache["n"], cache["m"])
+            q, k, v, ig, fg, tuple(_layer_of(cache[s], at) for s in "Cnm")
         )
-        return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
+        return (_mlstm_out(cfg, p, x, hc, z),
+                _store(cache, {"C": C, "n": n, "m": m}, at))
 
 
 MLSTM_BLOCK = Block(
@@ -680,14 +730,14 @@ def _slstm_prefill(cfg, p, x, ctx: BlockCtx):
     return _slstm_post(cfg, p, x, hs), {"c": c, "n": n, "h": h, "m": m}
 
 
-def _slstm_decode(cfg, p, x, cache, ctx: BlockCtx):
+def _slstm_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
     with jax.named_scope("ssm"):
         gx = _slstm_gates(cfg, p, x)
         hs, (c, n, h, m) = slstm_decode_step(
-            gx, p["r_gates"], (cache["c"], cache["n"], cache["h"],
-                               cache["m"])
+            gx, p["r_gates"], tuple(_layer_of(cache[s], at) for s in "cnhm")
         )
-    return _slstm_post(cfg, p, x, hs), {"c": c, "n": n, "h": h, "m": m}
+        state = _store(cache, {"c": c, "n": n, "h": h, "m": m}, at)
+    return _slstm_post(cfg, p, x, hs), state
 
 
 SLSTM_BLOCK = Block(

@@ -10,6 +10,8 @@ single unrolled block where the arch is non-uniform:
   hybrid (Hymba)        [SWA-hybrid runs] + [global-attn hybrid singles]
 
 The same block numerics serve train, prefill and decode (kv/ssm/cell cache).
+Decode carries a scanned segment's stacked cache through its scan, so each
+step updates the donated cache in place (``_decode_segment``).
 Each part runs under a ``jax.named_scope`` of ``scopes.SCOPES``: ``embed``,
 ``layers`` (each segment's stack), ``final_norm`` and ``lm_head`` here, the
 block's parts in ``blocks.py``.
@@ -422,31 +424,40 @@ class Model:
         return logits, {"pos": cache["pos"] + 1, "segments": new_caches}
 
     def _decode_segment(self, seg: Segment, p, x, c, ctx: BlockCtx):
+        """One segment's decode. A scanned segment carries its stacked
+        cache through the scan (the donated buffer, updated in place) and
+        scans over the layer weights and indices; each block updates its
+        own layer of the cache."""
         cfg = self.cfg
         if seg.kind == "vlm_group":
             attn, cross = BLOCKS["attn"], BLOCKS["cross"]
 
-            def group(xc, gpc):
-                gp, gc = gpc
+            def group(carry, gpi):
+                xc, gc = carry
+                gp, g = gpi
 
-                def one(xc2, lpc):
-                    lp, lc = lpc
-                    return attn.decode(cfg, lp, xc2, lc, ctx)
+                def one(carry2, lpj):
+                    lp, j = lpj
+                    return attn.decode(cfg, lp, *carry2, ctx, at=(g, j)), None
 
-                xc, cs = jax.lax.scan(one, xc, (gp["self"], gc["self"]))
-                xc, cc = cross.decode(cfg, gp["cross"], xc, gc["cross"], ctx)
-                return xc, {"self": cs, "cross": cc}
+                (xc, sc), _ = jax.lax.scan(one, (xc, gc["self"]),
+                                           (gp["self"], jnp.arange(seg.inner)))
+                xc, cc = cross.decode(cfg, gp["cross"], xc, gc["cross"], ctx,
+                                      at=(g,))
+                return (xc, {"self": sc, "cross": cc}), None
 
-            return jax.lax.scan(group, x, (p, c))
+            (x, c), _ = jax.lax.scan(group, (x, c), (p, jnp.arange(seg.n)))
+            return x, c
         blk = BLOCKS[seg.kind]
         if not seg.scanned:
             return blk.decode(cfg, p, x, c, ctx)
 
-        def body(xc, lpc):
-            lp, lc = lpc
-            return blk.decode(cfg, lp, xc, lc, ctx)
+        def body(carry, lpi):
+            lp, i = lpi
+            return blk.decode(cfg, lp, *carry, ctx, at=(i,)), None
 
-        return jax.lax.scan(body, x, (p, c))
+        (x, c), _ = jax.lax.scan(body, (x, c), (p, jnp.arange(seg.n)))
+        return x, c
 
 
 def build_model(cfg: ArchConfig, **kw) -> Model:

@@ -7,7 +7,9 @@ path says which part of the model the operation belongs to:
 
 * model level: ``embed`` (token lookup, frontend, meta tokens),
   ``layers`` (each segment's layer stack: the scan's own slicing of its
-  stacked weights and cache and the stacking of its outputs),
+  stacked weights, and in prefill the stacking of each layer's new cache;
+  decode carries the stacked cache through the scan and each block
+  updates its own layer in place, under the block's scopes),
   ``final_norm``, ``lm_head`` (logits or the training loss);
 * block level: ``attn`` (norm, q/k/v, scores, output projection),
   ``kv_write`` (writing the key/value cache), ``mlp`` (dense FFN),

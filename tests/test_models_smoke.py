@@ -1,6 +1,9 @@
 """Per-arch smoke tests: reduced configs of all 10 assigned architectures
 run one forward + one full train step on CPU; shapes + finiteness asserted.
 Full configs are exercised only via the dry-run (ShapeDtypeStructs)."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,7 +67,8 @@ def test_train_step_no_nans(arch):
 
 
 @pytest.mark.parametrize("arch", ["smollm-135m", "qwen3-32b", "xlstm-125m",
-                                  "hymba-1.5b", "qwen3-moe-30b-a3b"])
+                                  "hymba-1.5b", "qwen3-moe-30b-a3b",
+                                  "qwen2-1.5b", "llama-3.2-vision-90b"])
 def test_decode_matches_forward(arch):
     """Prefill + 2 decode steps == full forward logits (f32, exact-ish)."""
     cfg = REGISTRY[arch].reduced()
@@ -74,13 +78,23 @@ def test_decode_matches_forward(arch):
     rng = np.random.default_rng(1)
     toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S + 2),
                                     dtype=np.int32))
-    batch = {"tokens": toks[:, :S]}
+    extra = {}
+    if cfg.family == "vlm":
+        # open the cross-attention gates (zero at init) so that decode
+        # reads the image k/v its cache carries
+        cross = params["segments"][0]["cross"]
+        for gate in ("gate_attn", "gate_ffn"):
+            cross[gate] = jnp.ones_like(cross[gate])
+        extra["images"] = jnp.asarray(rng.standard_normal(
+            (B, cfg.n_image_tokens, cfg.d_model), np.float32))
+    batch = {"tokens": toks[:, :S], **extra}
     lg, cache = model.prefill(params, batch, SMAX)
     lg1, cache = model.decode_step(params, cache, toks[:, S:S + 1])
     lg2, cache = model.decode_step(params, cache, toks[:, S + 1:S + 2])
 
     def ref(n):
-        h = model.forward(params, {"tokens": toks[:, :n]}, for_train=False)
+        h = model.forward(params, {"tokens": toks[:, :n], **extra},
+                          for_train=False)
         if cfg.n_meta_tokens:
             h = h[:, cfg.n_meta_tokens:]
         return model._logits(params, h[:, -1])
@@ -89,6 +103,31 @@ def test_decode_matches_forward(arch):
         want = ref(n)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "smollm-135m"])
+def test_decode_updates_stacked_cache_in_place(arch):
+    """Decode writes one position into the donated stacked k/v cache: no
+    copy of the whole stack, and scratch memory well under its size."""
+    cfg = dataclasses.replace(REGISTRY[arch].reduced(), n_layers=4)
+    model = build_model(cfg, remat=False)
+    B, SMAX = 8, 1024
+    cache = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jnp.int32 if x.shape == () else jnp.float32),
+        model.abstract_cache(B, SMAX))
+    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+        model.abstract(jnp.float32), cache,
+        jax.ShapeDtypeStruct((B, 1), jnp.int32)).compile()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.5 * cache_bytes, (temp, cache_bytes)
+    kv = cache["segments"][0]["k"].shape
+    stacked = "f32[%s]" % ",".join(map(str, kv))
+    copies = [ln for ln in compiled.as_text().splitlines()
+              if re.search(r"= " + re.escape(stacked) + r"\S* copy\(", ln)]
+    assert not copies, copies
 
 
 def test_vlm_needs_images():
