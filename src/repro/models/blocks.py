@@ -18,6 +18,14 @@ position's k/v into its slot of layer ``at`` and reads its layer's view of
 the result; recurrent states (mLSTM, sLSTM, Mamba) are written back whole
 at ``at``; image k/v are only read.
 
+An MoE layer's expert weights may come whole, stacked over the segment's
+layers (the serving scans pass them so, with no sharding rules): the
+expert kernel reads them in place at the layer ``at`` (the attention
+block's prefill takes ``at`` too). Its cache holds the routing's
+counters, int32 [2, 3]: prefill's row and the decode steps' row, each
+[rows routed, experts that got a row, most rows one expert got], the
+decode row summed over steps (its last entry the largest).
+
 Blocks are assembled into models by ``model.py`` as *segments* (scanned
 stacks of identical blocks, or single unrolled blocks where the arch is
 non-uniform: Hymba's 3 global-attention layers, xLSTM's sLSTM positions).
@@ -177,20 +185,29 @@ def _ffn_scope(cfg: ArchConfig):
     return jax.named_scope("moe" if cfg.is_moe else "mlp")
 
 
-def _ffn(cfg: ArchConfig, p, x, res):
+def _moe(cfg: ArchConfig, p, h, at=()):
+    """The MoE FFN of ``h``: (y, counters). Expert weights stacked over
+    layers (4-d) are read at layer ``at``."""
+    layer = at[0] if p["we_gate"].ndim == 4 else None
+    return moe_ffn(
+        h, p["router"], p["we_gate"], p["we_up"], p["we_down"],
+        k=cfg.experts_per_token, n_experts=cfg.n_experts,
+        capacity_factor=cfg.capacity_factor, layer=layer,
+    )
+
+
+def _ffn(cfg: ArchConfig, p, x, res, at=()):
+    """x + FFN(x); returns (x, routing counters or None)."""
+    counts = None
     with _ffn_scope(cfg):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         h2 = constrain(h2, "batch", "act_seq", None)
         if cfg.is_moe:
-            f = moe_ffn(
-                h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                k=cfg.experts_per_token, n_experts=cfg.n_experts,
-                capacity_factor=cfg.capacity_factor,
-            )
+            f, counts = _moe(cfg, p, h2, at)
         else:
             f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
         x = x + f * res
-        return constrain(x, "batch", "act_seq", None)
+        return constrain(x, "batch", "act_seq", None), counts
 
 
 def _attn_apply(cfg: ArchConfig, p, x, ctx: BlockCtx) -> jax.Array:
@@ -212,7 +229,7 @@ def _attn_apply(cfg: ArchConfig, p, x, ctx: BlockCtx) -> jax.Array:
         o = checkpoint_name(o, "attn_out")
         o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         x = x + o * res
-    return _ffn(cfg, p, x, res)
+    return _ffn(cfg, p, x, res)[0]
 
 
 def _attn_cache_len(cfg: ArchConfig, ctx: BlockCtx) -> int:
@@ -232,11 +249,15 @@ def _attn_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
     KV, hd = cfg.n_kv_heads, cfg.hd
     W = _attn_cache_len(cfg, ctx)
     spec = PT((B, KV, W, hd), _kv_axes(ctx), init="zeros")
-    return {"k": spec, "v": spec}
+    c = {"k": spec, "v": spec}
+    if cfg.is_moe:
+        c["moe"] = PT((2, 3), (None, None), init="zeros", dtype="int32")
+    return c
 
 
-def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx):
-    """Apply + build the cache slice from this layer's K/V."""
+def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx, at=()):
+    """Apply + build the cache slice from this layer's K/V (and the
+    routing's counters)."""
     res = _res_scale(cfg)
     with jax.named_scope("attn"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -250,10 +271,12 @@ def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx):
                           q_chunk=ctx.q_chunk)
         o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         x = x + o * res
-    x = _ffn(cfg, p, x, res)
+    x, counts = _ffn(cfg, p, x, res, at)
     with jax.named_scope("kv_write"):
         _, cache = _pack_attn_cache(cfg, k, v, ctx)
         cache = {n: constrain(a, *_kv_axes(ctx)) for n, a in cache.items()}
+    if counts is not None:
+        cache["moe"] = jnp.stack([counts, jnp.zeros_like(counts)])
     return x, cache
 
 
@@ -275,8 +298,20 @@ def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx, at=()):
         o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
         o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         x = x + o * res
-    x = _ffn_decode(cfg, p, x, res)
-    return x, {"k": ck, "v": cv}
+    x, counts = _ffn_decode(cfg, p, x, res, at)
+    new = {"k": ck, "v": cv}
+    if counts is not None:
+        new["moe"] = _add_counts(cache["moe"], counts, at)
+    return x, new
+
+
+def _add_counts(stacked, counts, at):
+    """Add one decode step's counters to the decode row of layer ``at``
+    (the largest group: the larger of the two)."""
+    old = _layer_of(stacked, at)[1]
+    row = jnp.concatenate([old[:2] + counts[:2],
+                           jnp.maximum(old[2:], counts[2:])])
+    return _put_at(stacked, row[None], at, 1)
 
 
 def _write_kv(cache, k, v, pos, ctx: BlockCtx, at=()):
@@ -305,18 +340,15 @@ def _write_kv(cache, k, v, pos, ctx: BlockCtx, at=()):
     return ck, cv, valid
 
 
-def _ffn_decode(cfg: ArchConfig, p, x, res):
+def _ffn_decode(cfg: ArchConfig, p, x, res, at=()):
+    counts = None
     with _ffn_scope(cfg):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         if cfg.is_moe:
-            f = moe_ffn(
-                h2, p["router"], p["we_gate"], p["we_up"], p["we_down"],
-                k=cfg.experts_per_token, n_experts=cfg.n_experts,
-                capacity_factor=cfg.capacity_factor,
-            )
+            f, counts = _moe(cfg, p, h2, at)
         else:
             f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
-        return x + f * res
+        return x + f * res, counts
 
 
 ATTN_BLOCK = Block(

@@ -11,7 +11,10 @@ single unrolled block where the arch is non-uniform:
 
 The same block numerics serve train, prefill and decode (kv/ssm/cell cache).
 Decode carries a scanned segment's stacked cache through its scan, so each
-step updates the donated cache in place (``_decode_segment``).
+step updates the donated cache in place (``_decode_segment``). With no
+sharding rules, serving scans pass an MoE segment's expert weights whole
+and the expert kernel reads each layer's in place (``_whole_weights``);
+the routing's counters ride in the cache (``moe_counters``).
 Each part runs under a ``jax.named_scope`` of ``scopes.SCOPES``: ``embed``,
 ``layers`` (each segment's stack), ``final_norm`` and ``lm_head`` here, the
 block's parts in ``blocks.py``.
@@ -24,10 +27,12 @@ from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs.base import ArchConfig, ShapeSpec
-from ..distributed.sharding import constrain
+from ..distributed.sharding import active_rules, constrain
 from .blocks import BLOCKS, BlockCtx, stackify
+from .moe import EXPERT_WEIGHTS
 from .layers import (
     PT,
     abstract_params,
@@ -395,6 +400,13 @@ class Model:
         blk = BLOCKS[seg.kind]
         if not seg.scanned:
             return blk.prefill(cfg, p, x, ctx)
+        whole, p = _whole_weights(p)
+        if whole:
+            def body_at(xc, lpi):
+                lp, i = lpi
+                return blk.prefill(cfg, {**lp, **whole}, xc, ctx, at=(i,))
+
+            return jax.lax.scan(body_at, x, (p, jnp.arange(seg.n)))
 
         def body(xc, lp):
             return blk.prefill(cfg, lp, xc, ctx)
@@ -451,13 +463,47 @@ class Model:
         blk = BLOCKS[seg.kind]
         if not seg.scanned:
             return blk.decode(cfg, p, x, c, ctx)
+        whole, p = _whole_weights(p)
 
         def body(carry, lpi):
             lp, i = lpi
+            if whole:
+                lp = {**lp, **whole}
             return blk.decode(cfg, lp, *carry, ctx, at=(i,)), None
 
         (x, c), _ = jax.lax.scan(body, (x, c), (p, jnp.arange(seg.n)))
         return x, c
+
+    @staticmethod
+    def moe_counters(cache) -> Dict[str, int]:
+        """The routing counters a cache carries, fetched in one transfer
+        and summed over layers: rows routed, experts that got a row
+        (summed over layers and steps) and the most rows one expert got,
+        for prefill and for the decode steps since. Empty for a model
+        without experts."""
+        arrs = [c["moe"] for c in cache["segments"]
+                if isinstance(c, dict) and "moe" in c]
+        if not arrs:
+            return {}
+        a = np.concatenate([np.asarray(x).reshape(-1, 2, 3)
+                            for x in jax.device_get(arrs)])
+        out = {}
+        for r, phase in enumerate(("prefill", "decode")):
+            out[f"moe_{phase}_rows"] = int(a[:, r, 0].sum())
+            out[f"moe_{phase}_experts"] = int(a[:, r, 1].sum())
+            out[f"moe_{phase}_largest"] = int(a[:, r, 2].max())
+        return out
+
+
+def _whole_weights(p):
+    """(expert weights the serving scan passes whole, the rest to scan
+    over): an MoE segment's stacked expert weights when no sharding rules
+    are active, which the expert kernel reads in place per layer; else
+    ({}, p)."""
+    if active_rules() is not None or EXPERT_WEIGHTS[0] not in p:
+        return {}, p
+    return ({n: p[n] for n in EXPERT_WEIGHTS},
+            {n: v for n, v in p.items() if n not in EXPERT_WEIGHTS})
 
 
 def build_model(cfg: ArchConfig, **kw) -> Model:

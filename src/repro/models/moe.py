@@ -1,8 +1,15 @@
-"""Mixture-of-Experts FFN: top-k routing with capacity, two implementations.
+"""Mixture-of-Experts FFN: top-k routing, four implementations.
 
+``moe_sparse`` — dropless serving path on one device: the T·k assignments
+                 are sorted by expert, the experts run as one grouped
+                 matrix product over the group sizes (megablox ``gmm``, a
+                 Pallas kernel that visits only the row tiles of experts
+                 that got rows, so it reads only those experts' weights),
+                 and the rows are un-sorted and added up by their gates. No
+                 capacity, no dropped row, no buffer of E·T rows.
 ``moe_dense``  — reference oracle: every expert computed for every token,
-                 masked by routing weights. O(E·T·d·f) compute — used by CPU
-                 smoke tests and as the numeric ground truth for the EP path.
+                 masked by routing weights. O(E·T·d·f) compute — the
+                 numeric ground truth for the other paths in tests.
 
 ``moe_ep``     — production expert-parallel path (shard_map): tokens are
                  bucketed by destination shard with a sort (NO one-hot
@@ -11,23 +18,36 @@
                  'model' axis, run through the local experts as one batched
                  einsum, and returned. Capacity-dropped tokens fall back to
                  the residual (standard token-dropping semantics).
+``moe_onehot`` — one-hot GSPMD expert parallelism for decode (capacity).
 
 Routing: softmax over experts, top-k, renormalized gates (Qwen3-MoE style;
 Phi-3.5's sparsemixer is approximated by the same renormalized top-k —
 recorded in DESIGN.md §assumption-changes).
+
+The sparse path names its parts for the profiler: ``router`` (router
+logits, softmax, top-k), ``dispatch`` (sort, gather, un-sort, combine) and
+``experts`` (the grouped products). ``moe_ffn`` returns with the output,
+whichever path it takes, the routing's counters, int32 ``[rows routed,
+experts that got a row, most rows one expert got]``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 from jax.sharding import PartitionSpec as P
 
 from ..distributed.sharding import ShardingRules, active_rules
 
-__all__ = ["moe_dense", "moe_ep", "moe_ffn", "router_topk"]
+__all__ = ["EXPERT_WEIGHTS", "moe_dense", "moe_ep", "moe_ffn", "moe_sparse",
+           "router_topk", "expert_counts"]
+
+# the expert weights, which the sparse path can read in place from a stack
+# over layers
+EXPERT_WEIGHTS = ("we_gate", "we_up", "we_down")
 
 
 def router_topk(x, w_router, k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -62,6 +82,103 @@ def moe_dense(x, w_router, we_gate, we_up, we_down, *, k: int) -> jax.Array:
         jnp.broadcast_to(x, (E,) + x.shape), we_gate, we_up, we_down
     )  # [E,T,d]
     return jnp.einsum("te,etd->td", comb.astype(x.dtype), ys)
+
+
+def expert_counts(ids, n_experts: int) -> jax.Array:
+    """int32 [rows routed, experts with at least one row, largest group]
+    of the assignments ``ids`` [T,k]."""
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[ids.reshape(-1)].add(1)
+    return _counts(sizes)
+
+
+def _counts(sizes):
+    return jnp.stack([sizes.sum(), (sizes > 0).sum(dtype=jnp.int32),
+                      sizes.max()]).astype(jnp.int32)
+
+
+# VMEM that one grouped-product call may use for its double-buffered
+# blocks and f32 accumulator; under the 16 MiB that Mosaic scopes by
+# default on a v5e
+_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _tiling(k: int, n: int, tm: int, itemsize: int):
+    """(tm, tk, tn) of one ``gmm`` call: the whole contraction and output
+    width where they fit the budget, halving the output width until they
+    do."""
+    tk, tn = min(k, 2048), n
+
+    def vmem(tn):
+        return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+    while vmem(tn) > _VMEM_BUDGET and tn % 256 == 0:
+        tn //= 2
+    return tm, tk, tn
+
+
+def _next_pow2(n: int) -> int:
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
+def _gmm(lhs, rhs, groups, tiling):
+    """``lhs`` rows grouped by ``groups`` times each group's ``rhs``: the
+    compiled Mosaic kernel on a TPU, the same kernel interpreted elsewhere
+    (chosen when the program is lowered, by its platform)."""
+    def run(interpret):
+        return lambda a, b, g: megablox.gmm(a, b, g, lhs.dtype, tiling, None,
+                                            None, False, interpret)
+
+    return jax.lax.platform_dependent(lhs, rhs, groups, tpu=run(False),
+                                      default=run(True))
+
+
+def moe_sparse(x, w_router, we_gate, we_up, we_down, *, k: int,
+               layer: Optional[jax.Array] = None):
+    """Dropless top-k MoE. x [T,d] -> (y [T,d], counters int32[3]).
+
+    The expert weights are one layer's [E,d,f]/[E,f,d] or, with ``layer``,
+    a stack [L,E,d,f]/[L,E,f,d] that the kernel reads in place at that
+    layer: its group sizes then cover all L·E experts and are zero outside
+    the layer, so no other layer's weights are read and no layer's are
+    copied out of the stack.
+    """
+    T, d = x.shape
+    E = w_router.shape[-1]
+    N = T * k
+    with jax.named_scope("router"):
+        gates, ids, _ = router_topk(x, w_router, k)
+    with jax.named_scope("dispatch"):
+        flat = ids.reshape(-1)
+        order = jnp.argsort(flat, stable=True)          # rows by expert
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        # one row tile per expert's share of the rows: 16 rows in decode,
+        # up to 512 in prefill; the kernel needs whole tiles of rows
+        tm = int(min(512, max(16, _next_pow2(N // E))))
+        M = -(-N // tm) * tm
+        xs = jnp.take(x, order // k, axis=0)
+        xs = jnp.pad(xs, ((0, M - N), (0, 0)))
+        groups = sizes
+        if layer is not None:
+            L = we_gate.shape[0]
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), sizes, (layer * E,))
+            we_gate, we_up, we_down = (
+                w.reshape((L * E,) + w.shape[2:])
+                for w in (we_gate, we_up, we_down))
+        f = we_gate.shape[-1]
+    with jax.named_scope("experts"):
+        up = _tiling(d, f, tm, x.dtype.itemsize)
+        g = _gmm(xs, we_gate, groups, up)
+        u = _gmm(xs, we_up, groups, up)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        ys = _gmm(h, we_down, groups, _tiling(f, d, tm, x.dtype.itemsize))
+    with jax.named_scope("dispatch"):
+        # row j of the assignments sits at sorted position inv[j]
+        inv = jnp.zeros((N,), jnp.int32).at[order].set(
+            jnp.arange(N, dtype=jnp.int32))
+        y = jnp.take(ys, inv, axis=0).reshape(T, k, d).astype(jnp.float32)
+        y = jnp.einsum("tkd,tk->td", y, gates).astype(x.dtype)
+    return y, _counts(sizes)
 
 
 def _bucket_by(dest, n_buckets: int, cap: int, src_ids):
@@ -214,27 +331,36 @@ def constrain_expert(xe):
 
 
 def moe_ffn(x, w_router, we_gate, we_up, we_down, *, k, n_experts,
-            capacity_factor) -> jax.Array:
-    """Dispatch on active sharding rules: sort-based shard_map EP for bulk
-    token streams, one-hot GSPMD EP when the token dim cannot split over
-    the EP axis (decode), dense oracle otherwise. x [B,S,d] -> [B,S,d].
+            capacity_factor, layer: Optional[jax.Array] = None):
+    """x [B,S,d] -> (y [B,S,d], counters int32[3]).
+
+    With no sharding rules: the dropless sparse path (expert weights
+    stacked over layers and read at ``layer`` when it is given). Under
+    rules: sort-based shard_map EP for bulk token streams, one-hot GSPMD EP
+    when the token dim cannot split over the EP axis (decode), the dense
+    oracle otherwise; these take one layer's weights, and their counters
+    come from a second router product.
     """
     rules = active_rules()
     B, S, d = x.shape
-    if rules is not None and rules.moe_impl == "ep" and rules.ep_axis is not None:
+    x2 = x.reshape(-1, d)
+    if rules is None:
+        y, counts = moe_sparse(x2, w_router, we_gate, we_up, we_down, k=k,
+                               layer=layer)
+        return y.reshape(B, S, d), counts
+    counts = expert_counts(router_topk(x2, w_router, k)[1], n_experts)
+    if rules.moe_impl == "ep" and rules.ep_axis is not None:
         ep_size = rules.mesh.shape[rules.ep_axis]
         if S % ep_size == 0:
             return moe_ep(
                 x, w_router, we_gate, we_up, we_down,
                 k=k, n_experts=n_experts, capacity_factor=capacity_factor,
                 rules=rules,
-            )
+            ), counts
         y = moe_onehot(
-            x.reshape(-1, d), w_router, we_gate, we_up, we_down,
+            x2, w_router, we_gate, we_up, we_down,
             k=k, n_experts=n_experts, capacity_factor=capacity_factor,
         )
-        return y.reshape(B, S, d)
-    y = moe_dense(
-        x.reshape(-1, d), w_router, we_gate, we_up, we_down, k=k
-    )
-    return y.reshape(B, S, d)
+        return y.reshape(B, S, d), counts
+    y = moe_dense(x2, w_router, we_gate, we_up, we_down, k=k)
+    return y.reshape(B, S, d), counts

@@ -14,11 +14,16 @@ path says which part of the model the operation belongs to:
 * block level: ``attn`` (norm, q/k/v, scores, output projection),
   ``kv_write`` (writing the key/value cache), ``mlp`` (dense FFN),
   ``moe`` (routed experts), ``ssm`` (recurrent mixers: Mamba, mLSTM,
-  sLSTM), ``cross_attn`` (attention to image tokens).
+  sLSTM), ``cross_attn`` (attention to image tokens);
+* inside ``moe``, on its dropless sparse path (``moe.moe_sparse``):
+  ``router`` (router product, softmax, top-k), ``dispatch`` (sorting the
+  rows by expert, gathering them, un-sorting the results and adding them
+  up by their gates) and ``experts`` (the grouped products and the SwiGLU
+  between them).
 
 The benchmark's trace reader imports this tuple, so a scope renamed in
 the model without renaming it here reads as unscoped time.
 """
 
 SCOPES = ("embed", "layers", "final_norm", "lm_head", "attn", "kv_write",
-          "mlp", "moe", "ssm", "cross_attn")
+          "mlp", "moe", "ssm", "cross_attn", "router", "dispatch", "experts")

@@ -23,6 +23,13 @@ prefill gives, step n the n-th decode step, and ``pos`` the cache position
 that step writes. ``live`` counts the rows still generating after the
 loop and ``evicted`` the rows it evicted. With the profiler off a span
 costs about a microsecond.
+
+A model with routed experts carries the routing's counters in its cache
+(``Model.moe_counters``); the engine fetches them once per batch, after
+its last step, and puts them on ``serve.batch``: ``moe_prefill_rows``,
+``moe_prefill_experts``, ``moe_prefill_largest`` and the same for
+``moe_decode_*`` (rows routed and experts that got a row, summed over
+layers and steps; the most rows one expert got).
 """
 from __future__ import annotations
 
@@ -131,7 +138,7 @@ class ServeEngine:
             b = self._batch_id
             rows, prompt_len = len(reqs), max(len(r.prompt) for r in reqs)
             with _span("serve.batch", batch=b, rows=rows,
-                       prompt_len=prompt_len):
+                       prompt_len=prompt_len) as batch_span:
                 with _span("serve.prefill", batch=b, rows=rows,
                            prompt_len=prompt_len):
                     logits, cache = self._prefill_batch(reqs)
@@ -152,6 +159,11 @@ class ServeEngine:
                             self.params, cache,
                             jnp.asarray(next_tok)[:, None])
                     pos += 1
+                counters = getattr(self.model, "moe_counters", None)
+                if counters is not None:
+                    moe = counters(cache)
+                    if moe:
+                        batch_span.set_metadata(**moe)
         out = {rid: r.generated for rid, r in self.completed.items()}
         out.update({rid: r.generated
                     for rid, r in self.evicted_partial.items()})

@@ -105,24 +105,32 @@ def test_decode_matches_forward(arch):
                                    rtol=2e-3, atol=2e-3)
 
 
-@pytest.mark.parametrize("arch", ["qwen2-1.5b", "smollm-135m"])
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "smollm-135m",
+                                  "qwen3-moe-30b-a3b"])
 def test_decode_updates_stacked_cache_in_place(arch):
     """Decode writes one position into the donated stacked k/v cache: no
-    copy of the whole stack, and scratch memory well under its size."""
+    copy of the whole stack, and scratch memory well under its size. (On
+    the CPU the MoE's grouped-product kernel runs interpreted, which keeps
+    its operands, the stacked expert weights, in scratch; its scratch on
+    the chip is checked in ``tests/test_tpu_compile.py``.)"""
     cfg = dataclasses.replace(REGISTRY[arch].reduced(), n_layers=4)
     model = build_model(cfg, remat=False)
     B, SMAX = 8, 1024
     cache = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(
-            x.shape, jnp.int32 if x.shape == () else jnp.float32),
+            x.shape, x.dtype if jnp.issubdtype(x.dtype, jnp.integer)
+            else jnp.float32),
         model.abstract_cache(B, SMAX))
+    params = model.abstract(jnp.float32)
     compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
-        model.abstract(jnp.float32), cache,
-        jax.ShapeDtypeStruct((B, 1), jnp.int32)).compile()
+        params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32)).compile()
     cache_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(cache))
+    interpreted = sum(x.size * x.dtype.itemsize
+                      for n, x in params["segments"][0].items()
+                      if n.startswith("we_"))
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 0.5 * cache_bytes, (temp, cache_bytes)
+    assert temp < 0.5 * cache_bytes + interpreted, (temp, cache_bytes)
     kv = cache["segments"][0]["k"].shape
     stacked = "f32[%s]" % ",".join(map(str, kv))
     copies = [ln for ln in compiled.as_text().splitlines()

@@ -131,3 +131,58 @@ def test_decode_updates_stacked_cache_in_place(one_chip):
     cache_bytes = sum(x.size * x.dtype.itemsize
                       for x in jax.tree_util.tree_leaves(cache))
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * cache_bytes
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_qwen3_moe_serving_fits_one_chip(one_chip, program):
+    """Qwen3-30B-A3B's serving programs at published widths and the
+    benchmark's cut (8 layers, all 128 experts), batch 32, 512-token
+    prompts, 768 slots: the dropless sparse expert layer makes no buffer
+    of E·T·d or E·T·f elements (the dense oracle's broadcast of every
+    token to every expert), and the program's scratch fits beside its
+    weights and cache in the v5e's 16 GB."""
+    import dataclasses
+    import re
+
+    from repro.configs import REGISTRY
+    from repro.models import build_model
+    from repro.serve.engine import make_decode_fn, make_prefill_fn
+
+    cfg = dataclasses.replace(REGISTRY["qwen3-moe-30b-a3b"], n_layers=8)
+    model = build_model(cfg)
+    B, P, SMAX = 32, 512, 768
+    E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+
+    def sds(x):
+        return _sds(one_chip, x.shape, x.dtype)
+
+    params = jax.tree_util.tree_map(sds, model.abstract())
+    if program == "prefill":
+        T = B * P
+        compiled = jax.jit(make_prefill_fn(model, None, SMAX)).lower(
+            params, {"tokens": _sds(one_chip, (B, P), jnp.int32)}).compile()
+    else:
+        T = B
+        cache = jax.tree_util.tree_map(sds, model.abstract_cache(B, SMAX))
+        compiled = jax.jit(make_decode_fn(model, None),
+                           donate_argnums=(1,)).lower(
+            params, cache, _sds(one_chip, (B, 1), jnp.int32)).compile()
+    # a weight's shape, a layer of it, or its experts of all layers in a
+    # row, may hold as many elements
+    weights = set()
+    for x in jax.tree_util.tree_leaves(params):
+        weights |= {x.shape, x.shape[1:],
+                    (int(np.prod(x.shape[:2])),) + x.shape[2:]}
+    dense = {E * T * d, E * T * f}
+    found = set()
+    for m in re.finditer(r"= \w+\[([\d,]+)\]", compiled.as_text()):
+        shape = tuple(int(s) for s in m.group(1).split(","))
+        if (int(np.prod(shape)) in dense
+                and tuple(s for s in shape if s != 1) not in weights):
+            found.add(shape)
+    assert not found, found
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert total < 16e9, (total, ma.temp_size_in_bytes)
+    assert "tpu_custom_call" in compiled.as_text()
