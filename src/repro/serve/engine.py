@@ -12,17 +12,26 @@ cannot head-of-line-block the batch.
 ``jax.profiler.TraceAnnotation`` spans, each carrying its counts as span
 arguments (``batch`` is the id all spans of one batch share):
 
-  serve.batch     one batch, to its last token    batch, rows, prompt_len
+  serve.batch     one batch, to its last token    batch, rows, prompt_len,
+                  and the batch's one read        host_reads
   serve.prefill   padding, host->device, dispatch batch, rows, prompt_len
-  serve.sample    argmax, fetch to the host       batch, step
-  serve.schedule  append, retire, evict, requeue  batch, step, live, evicted
-  serve.decode    feeding the tokens, dispatch    batch, step, pos
+  serve.sample    argmax dispatched on the device batch, step
+  serve.schedule  retire, evict, requeue (counts) batch, step, live, evicted
+  serve.decode    dispatch, fed the device tokens batch, step, pos
 
 The last four nest in ``serve.batch``; ``step`` 0 is the token that
 prefill gives, step n the n-th decode step, and ``pos`` the cache position
 that step writes. ``live`` counts the rows still generating after the
 loop and ``evicted`` the rows it evicted. With the profiler off a span
 costs about a microsecond.
+
+Greedy tokens stay on the device: each step's argmax feeds the next
+decode step directly, and scheduling needs only counts the host already
+holds, so the host dispatches a batch's steps without waiting on one.
+After the last step it fetches every step's tokens in one transfer,
+inside ``serve.batch`` and outside the phases, and appends each row's
+tokens of the steps it was live. ``host_reads`` counts the batch's
+blocking device-to-host reads: that one, plus the expert counters' below.
 
 A model with routed experts carries the routing's counters in its cache
 (``Model.moe_counters``); the engine fetches them once per batch, after
@@ -47,6 +56,12 @@ from ..models.model import Model
 __all__ = ["make_prefill_fn", "make_decode_fn", "ServeEngine", "Request"]
 
 _span = jax.profiler.TraceAnnotation
+
+
+@jax.jit
+def _greedy(logits):
+    """Each row's greedy token, as the next decode step takes it: [B,1]."""
+    return jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
 
 
 def make_prefill_fn(model: Model, rules: Optional[ShardingRules],
@@ -143,43 +158,50 @@ class ServeEngine:
                            prompt_len=prompt_len):
                     logits, cache = self._prefill_batch(reqs)
                 live = list(range(rows))
+                # rows leave ``live`` for good, so row i was live at steps
+                # 0 .. taken[i] - 1
+                taken = [0] * rows
+                steps = []          # each step's tokens [rows, 1], on device
                 step, pos = 0, prompt_len
                 while True:
                     with _span("serve.sample", batch=b, step=step):
-                        next_tok = np.asarray(jnp.argmax(logits, -1),
-                                              np.int32)
+                        tok = _greedy(logits)
+                        steps.append(tok)
                     with _span("serve.schedule", batch=b, step=step) as sp:
-                        evicted = self._schedule(reqs, live, next_tok)
+                        evicted = self._schedule(reqs, live, taken)
                         sp.set_metadata(live=len(live), evicted=evicted)
                     if not live:
                         break
                     step += 1
                     with _span("serve.decode", batch=b, step=step, pos=pos):
-                        logits, cache = self.decode_fn(
-                            self.params, cache,
-                            jnp.asarray(next_tok)[:, None])
+                        logits, cache = self.decode_fn(self.params, cache,
+                                                       tok)
                     pos += 1
+                # the batch's one read: every step's tokens
+                grid = np.concatenate(jax.device_get(steps), axis=1)
+                for r, row, n in zip(reqs, grid, taken):
+                    r.generated.extend(row[:n].tolist())
                 counters = getattr(self.model, "moe_counters", None)
-                if counters is not None:
-                    moe = counters(cache)
-                    if moe:
-                        batch_span.set_metadata(**moe)
+                moe = counters(cache) if counters is not None else {}
+                batch_span.set_metadata(host_reads=1 + bool(moe), **moe)
         out = {rid: r.generated for rid, r in self.completed.items()}
         out.update({rid: r.generated
                     for rid, r in self.evicted_partial.items()})
         return out
 
     def _schedule(self, reqs: List[Request], live: List[int],
-                  next_tok: np.ndarray) -> int:
-        """Append each live row's token; retire finished rows and evict
-        stragglers from ``live`` (re-queued while retries remain).
-        Returns the number of rows evicted."""
+                  taken: List[int]) -> int:
+        """Count one token for each live row; retire finished rows and
+        evict stragglers from ``live`` (re-queued while retries remain),
+        from counts alone: a row has generated the tokens it held before
+        the batch and ``taken`` since. Returns the number of rows
+        evicted."""
         evicted = 0
         for i in list(live):
             r = reqs[i]
-            r.generated.append(int(next_tok[i]))
+            taken[i] += 1
             r.steps_used += 1
-            if r.done:
+            if len(r.generated) + taken[i] >= r.max_new:
                 live.remove(i)
                 self.completed[r.rid] = r
             elif (r.deadline_steps is not None

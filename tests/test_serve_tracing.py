@@ -115,7 +115,7 @@ def test_tokens_with_the_profiler_on_equal_those_with_it_off(traced):
 
 def test_each_span_carries_its_arguments(traced):
     evs = traced["events"]
-    keys = {"serve.batch": {"batch", "rows", "prompt_len"},
+    keys = {"serve.batch": {"batch", "rows", "prompt_len", "host_reads"},
             "serve.prefill": {"batch", "rows", "prompt_len"},
             "serve.sample": {"batch", "step"},
             "serve.schedule": {"batch", "step", "live", "evicted"},
