@@ -1,11 +1,11 @@
-"""Layer blocks: parameter templates + train/prefill/decode forward paths.
+"""Layer blocks: parameter templates + prefill/decode forward paths.
 
-Each block *kind* is a ``Block`` record with four functions sharing one
-numeric core, so the smoke tests (train path) validate the same math the
-serving paths use:
+Each block *kind* is a ``Block`` record with three functions sharing one
+numeric core. Training's forward is ``prefill``'s hidden states (the cache
+it also builds is dead code there, and the compiler drops it), so the
+smoke tests (train path) validate the very math the serving paths use:
 
   template(cfg)                   -> pytree of PT
-  apply(cfg, p, x, ctx)           -> x                     (train / no-cache)
   prefill(cfg, p, x, ctx)         -> (x, cache_slice)
   decode(cfg, p, x, cache, ctx, at=()) -> (x, new_cache)
   cache_template(cfg, B, ctx)     -> pytree of PT (cache shapes/axes/dtypes)
@@ -42,6 +42,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..configs.base import ArchConfig
 from ..distributed.sharding import constrain
@@ -77,7 +78,6 @@ class BlockCtx:
 class Block:
     kind: str
     template: Callable[[ArchConfig], Any]
-    apply: Callable[..., jax.Array]
     prefill: Callable[..., Tuple[jax.Array, Any]]
     decode: Callable[..., Tuple[jax.Array, Any]]
     cache_template: Callable[[ArchConfig, int, BlockCtx], Any]
@@ -210,28 +210,6 @@ def _ffn(cfg: ArchConfig, p, x, res, at=()):
         return constrain(x, "batch", "act_seq", None), counts
 
 
-def _attn_apply(cfg: ArchConfig, p, x, ctx: BlockCtx) -> jax.Array:
-    res = _res_scale(cfg)
-    with jax.named_scope("attn"):
-        h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        h = constrain(h, "batch", "act_seq", None)
-        q, k, v = _qkv(cfg, p, h, ctx.rope)
-        if ctx.window > 0 and ctx.n_sink > 0:
-            o = sink_banded_attention(q, k, v, window=ctx.window,
-                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-        else:
-            o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
-                          q_chunk=ctx.q_chunk)
-        # named for selective remat: policy 'save-attn' keeps this
-        # [B,S,H,hd] tensor so backward never re-runs the O(S^2) score
-        # pipeline
-        from jax.ad_checkpoint import checkpoint_name
-        o = checkpoint_name(o, "attn_out")
-        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-        x = x + o * res
-    return _ffn(cfg, p, x, res)[0]
-
-
 def _attn_cache_len(cfg: ArchConfig, ctx: BlockCtx) -> int:
     if ctx.window > 0:
         return ctx.n_sink + ctx.window
@@ -255,25 +233,51 @@ def _attn_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
     return c
 
 
+def _prompt_attn(cfg: ArchConfig, p, x, ctx: BlockCtx):
+    """Self-attention over the prompt, before ``wo``: ``ln1``, q/k/v, then
+    sink-banded or plain attention. Returns (h, o [B,S,H,hd], k, v)."""
+    h = rms_norm(x, p["ln1"], cfg.norm_eps)
+    h = constrain(h, "batch", "act_seq", None)
+    q, k, v = _qkv(cfg, p, h, ctx.rope)
+    if ctx.window > 0 and ctx.n_sink > 0:
+        o = sink_banded_attention(q, k, v, window=ctx.window,
+                                  n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
+    else:
+        o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
+                      q_chunk=ctx.q_chunk)
+    return h, o, k, v
+
+
+def _step_attn(cfg: ArchConfig, p, h, cache, ctx: BlockCtx, at=()):
+    """One decode position's self-attention on ``h`` = ln1(x) [B,1,d]:
+    writes its k/v into layer ``at`` of the cache and attends over that
+    layer. Returns (o [B,1,d] after ``wo``, new k cache, new v cache)."""
+    rope = (rope_at(ctx.pos, cfg.hd, cfg.rope_theta)
+            if ctx.rope is not None else None)
+    q, k, v = _qkv(cfg, p, h, rope)
+    # decode shards the CACHE over 'model' (flash-decoding); q must keep
+    # heads replicated or GSPMD all-gathers the cache slice every layer
+    q = constrain(q, "batch", None, None, None)
+    ck, cv, valid = _write_kv(cache, k, v, ctx.pos, ctx, at)
+    o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
+    return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), ck, cv
+
+
 def _attn_prefill(cfg: ArchConfig, p, x, ctx: BlockCtx, at=()):
-    """Apply + build the cache slice from this layer's K/V (and the
-    routing's counters)."""
+    """The layer over the prompt, and its cache slice from this layer's K/V
+    (and the routing's counters)."""
     res = _res_scale(cfg)
     with jax.named_scope("attn"):
-        h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        h = constrain(h, "batch", "act_seq", None)
-        q, k, v = _qkv(cfg, p, h, ctx.rope)
-        if ctx.window > 0 and ctx.n_sink > 0:
-            o = sink_banded_attention(q, k, v, window=ctx.window,
-                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-        else:
-            o = attention(q, k, v, causal=ctx.causal, window=ctx.window,
-                          q_chunk=ctx.q_chunk)
+        _, o, k, v = _prompt_attn(cfg, p, x, ctx)
+        # named for selective remat: policy 'save-attn' keeps this
+        # [B,S,H,hd] tensor so backward never re-runs the O(S^2) score
+        # pipeline
+        o = checkpoint_name(o, "attn_out")
         o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
         x = x + o * res
     x, counts = _ffn(cfg, p, x, res, at)
     with jax.named_scope("kv_write"):
-        _, cache = _pack_attn_cache(cfg, k, v, ctx)
+        cache = _pack_attn_cache(cfg, k, v, ctx)
         cache = {n: constrain(a, *_kv_axes(ctx)) for n, a in cache.items()}
     if counts is not None:
         cache["moe"] = jnp.stack([counts, jnp.zeros_like(counts)])
@@ -284,19 +288,9 @@ def _attn_decode(cfg: ArchConfig, p, x, cache, ctx: BlockCtx, at=()):
     """x [B,1,d]; cache {k,v [*stack,B,KV,W,hd]}; ctx.pos = absolute
     position."""
     res = _res_scale(cfg)
-    pos = ctx.pos
     with jax.named_scope("attn"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        rope = (rope_at(pos, cfg.hd, cfg.rope_theta)
-                if ctx.rope is not None else None)
-        q, k, v = _qkv(cfg, p, h, rope)
-        # decode shards the CACHE over 'model' (flash-decoding); q must
-        # keep heads replicated or GSPMD all-gathers the cache slice every
-        # layer
-        q = constrain(q, "batch", None, None, None)
-        ck, cv, valid = _write_kv(cache, k, v, pos, ctx, at)
-        o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
-        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        o, ck, cv = _step_attn(cfg, p, h, cache, ctx, at)
         x = x + o * res
     x, counts = _ffn_decode(cfg, p, x, res, at)
     new = {"k": ck, "v": cv}
@@ -354,7 +348,6 @@ def _ffn_decode(cfg: ArchConfig, p, x, res, at=()):
 ATTN_BLOCK = Block(
     kind="attn",
     template=_attn_template,
-    apply=_attn_apply,
     prefill=_attn_prefill,
     decode=_attn_decode,
     cache_template=_attn_cache_template,
@@ -408,11 +401,6 @@ def _cross_core(cfg, p, x, k_img, v_img):
         return constrain(x, "batch", "act_seq", None)
 
 
-def _cross_apply(cfg, p, x, ctx: BlockCtx):
-    k_img, v_img = _img_kv(p, ctx.img, cfg.norm_eps)
-    return _cross_core(cfg, p, x, k_img, v_img)
-
-
 def _cross_prefill(cfg, p, x, ctx: BlockCtx):
     k_img, v_img = _img_kv(p, ctx.img, cfg.norm_eps)
     return _cross_core(cfg, p, x, k_img, v_img), {"k": k_img, "v": v_img}
@@ -434,7 +422,6 @@ def _cross_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 CROSS_BLOCK = Block(
     kind="cross",
     template=_cross_template,
-    apply=_cross_apply,
     prefill=_cross_prefill,
     decode=_cross_decode,
     cache_template=_cross_cache_template,
@@ -480,27 +467,21 @@ def _hybrid_template(cfg: ArchConfig) -> Dict[str, Any]:
     }
 
 
-def _hybrid_mamba(cfg, p, h, state=None, return_state=False, decode=False):
+def _hybrid_mamba(cfg, p, h, state=None):
+    """The Mamba heads on ``h`` = ln1(x): over the prompt from a zero
+    state, or one decode step from ``state``. Returns (out, final state)."""
     with jax.named_scope("ssm"):
         xz = jnp.einsum("bsd,de->bse", h, p["w_in"])
         xz = constrain(xz, "batch", None, "ssm_inner")
         x_in, z = jnp.split(xz, 2, axis=-1)
+        args = (x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
+                p["a_log"], p["d_skip"])
         kw = dict(n_state=cfg.ssm_state, dt_rank=_dt_rank(cfg))
-        if decode:
-            y, st = mamba_decode_mix(
-                x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-                p["a_log"], p["d_skip"], state=state, **kw)
-            out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
-            return out, st
-        if return_state:
-            y, st = mamba_mix(
-                x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-                p["a_log"], p["d_skip"], state=state, return_state=True, **kw)
-            out = jnp.einsum("bsd,de->bse", y, p["wo_m"])
-            return out, st
-        y = mamba_mix(x_in, z, p["conv_w"], p["w_x"], p["w_dt"], p["b_dt"],
-                      p["a_log"], p["d_skip"], state=state, **kw)
-        return jnp.einsum("bsd,de->bse", y, p["wo_m"])
+        if state is None:
+            y, st = mamba_mix(*args, **kw)
+        else:
+            y, st = mamba_decode_mix(*args, state=state, **kw)
+        return jnp.einsum("bsd,de->bse", y, p["wo_m"]), st
 
 
 def _hybrid_fuse(cfg, p, x, o_attn, o_ssm):
@@ -512,22 +493,6 @@ def _hybrid_fuse(cfg, p, x, o_attn, o_ssm):
         h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
         f = swiglu(h2, p["wg"], p["wi"], p["wo2"])
         return constrain(x + f, "batch", "act_seq", None)
-
-
-def _hybrid_apply(cfg, p, x, ctx: BlockCtx):
-    with jax.named_scope("attn"):
-        h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        h = constrain(h, "batch", "act_seq", None)
-        q, k, v = _qkv(cfg, p, h, ctx.rope)
-        if ctx.window > 0 and ctx.n_sink > 0:
-            o = sink_banded_attention(q, k, v, window=ctx.window,
-                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-        else:
-            o = attention(q, k, v, causal=True, window=ctx.window,
-                          q_chunk=ctx.q_chunk)
-        o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    o_ssm = _hybrid_mamba(cfg, p, h)
-    return _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
 
 
 def _hybrid_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
@@ -542,23 +507,14 @@ def _hybrid_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 
 def _hybrid_prefill(cfg, p, x, ctx: BlockCtx):
     with jax.named_scope("attn"):
-        h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        h = constrain(h, "batch", "act_seq", None)
-        q, k, v = _qkv(cfg, p, h, ctx.rope)
-        if ctx.window > 0 and ctx.n_sink > 0:
-            o = sink_banded_attention(q, k, v, window=ctx.window,
-                                      n_sink=ctx.n_sink, q_chunk=ctx.q_chunk)
-        else:
-            o = attention(q, k, v, causal=True, window=ctx.window,
-                          q_chunk=ctx.q_chunk)
+        h, o, k, v = _prompt_attn(cfg, p, x, ctx)
         o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    o_ssm, st = _hybrid_mamba(cfg, p, h, return_state=True)
+    o_ssm, st = _hybrid_mamba(cfg, p, h)
     xo = _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
 
     # attention cache (same ring layout as ATTN_BLOCK.prefill)
     with jax.named_scope("kv_write"):
-        _, attn_cache = _pack_attn_cache(cfg, k, v, ctx)
-    cache = dict(attn_cache)
+        cache = _pack_attn_cache(cfg, k, v, ctx)
     with jax.named_scope("ssm"):
         cache["conv"] = st.conv.astype(jnp.float32)
         cache["ssm"] = st.ssm.astype(jnp.float32)
@@ -584,23 +540,16 @@ def _pack_attn_cache(cfg, k, v, ctx: BlockCtx):
         idx = ns + (start + jnp.arange(tail)) % ctx.window
         ck = ck.at[:, idx].set(k[:, S - tail:])
         cv = cv.at[:, idx].set(v[:, S - tail:])
-    return None, {"k": ck.swapaxes(1, 2), "v": cv.swapaxes(1, 2)}
+    return {"k": ck.swapaxes(1, 2), "v": cv.swapaxes(1, 2)}
 
 
 def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
-    pos = ctx.pos
     with jax.named_scope("attn"):
         h = rms_norm(x, p["ln1"], cfg.norm_eps)
-        rope = (rope_at(pos, cfg.hd, cfg.rope_theta)
-                if ctx.rope is not None else None)
-        q, k, v = _qkv(cfg, p, h, rope)
-        q = constrain(q, "batch", None, None, None)
-        ck, cv, valid = _write_kv(cache, k, v, pos, ctx, at)
-        o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
-        o_attn = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        o_attn, ck, cv = _step_attn(cfg, p, h, cache, ctx, at)
     st = MambaState(conv=_layer_of(cache["conv"], at),
                     ssm=_layer_of(cache["ssm"], at))
-    o_ssm, st = _hybrid_mamba(cfg, p, h, state=st, decode=True)
+    o_ssm, st = _hybrid_mamba(cfg, p, h, state=st)
     xo = _hybrid_fuse(cfg, p, x, o_attn, o_ssm)
     with jax.named_scope("ssm"):
         state = _store(cache, {"conv": st.conv, "ssm": st.ssm}, at)
@@ -610,7 +559,6 @@ def _hybrid_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
 HYBRID_BLOCK = Block(
     kind="hybrid",
     template=_hybrid_template,
-    apply=_hybrid_apply,
     prefill=_hybrid_prefill,
     decode=_hybrid_decode,
     cache_template=_hybrid_cache_template,
@@ -655,13 +603,6 @@ def _mlstm_out(cfg, p, x, hc, z):
     return constrain(x + out, "batch", "act_seq", None)
 
 
-def _mlstm_apply(cfg, p, x, ctx: BlockCtx):
-    with jax.named_scope("ssm"):
-        q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
-        hc = mlstm_chunked(q, k, v, ig, fg)
-        return _mlstm_out(cfg, p, x, hc, z)
-
-
 def _mlstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
     H, hd = cfg.n_heads, cfg.hd
     return {
@@ -676,7 +617,7 @@ def _mlstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
 def _mlstm_prefill(cfg, p, x, ctx: BlockCtx):
     with jax.named_scope("ssm"):
         q, k, v, ig, fg, z = _mlstm_io(cfg, p, x)
-        hc, (C, n, m) = mlstm_chunked(q, k, v, ig, fg, return_state=True)
+        hc, (C, n, m) = mlstm_chunked(q, k, v, ig, fg)
         return _mlstm_out(cfg, p, x, hc, z), {"C": C, "n": n, "m": m}
 
 
@@ -693,7 +634,6 @@ def _mlstm_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
 MLSTM_BLOCK = Block(
     kind="mlstm",
     template=_mlstm_template,
-    apply=_mlstm_apply,
     prefill=_mlstm_prefill,
     decode=_mlstm_decode,
     cache_template=_mlstm_cache_template,
@@ -739,13 +679,6 @@ def _slstm_post(cfg, p, x, hs):
         return constrain(x + f, "batch", "act_seq", None)
 
 
-def _slstm_apply(cfg, p, x, ctx: BlockCtx):
-    with jax.named_scope("ssm"):
-        gx = _slstm_gates(cfg, p, x)
-        hs, _ = slstm_scan(gx, p["r_gates"])
-    return _slstm_post(cfg, p, x, hs)
-
-
 def _slstm_cache_template(cfg: ArchConfig, B: int, ctx: BlockCtx):
     H, hd = cfg.n_heads, cfg.hd
     v = PT((B, H, hd), ("batch", "heads", None), init="zeros", dtype="float32")
@@ -775,7 +708,6 @@ def _slstm_decode(cfg, p, x, cache, ctx: BlockCtx, at=()):
 SLSTM_BLOCK = Block(
     kind="slstm",
     template=_slstm_template,
-    apply=_slstm_apply,
     prefill=_slstm_prefill,
     decode=_slstm_decode,
     cache_template=_slstm_cache_template,

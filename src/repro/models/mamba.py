@@ -84,12 +84,12 @@ def mamba_mix(
     n_state: int,
     dt_rank: int,
     state: Optional[MambaState] = None,
-    return_state: bool = False,
-):
+) -> Tuple[jax.Array, MambaState]:
     """Full Mamba mixing on a pre-projected pair (x_in, z) [B,S,di].
 
     Caller provides in/out projections; this is the conv + selective-scan +
-    gate core so train/prefill/decode share one numeric path.
+    gate core. Returns the output and the final state (the cache prefill
+    hands to ``mamba_decode_mix``).
     """
     B, S, di = x_in.shape
     xc, conv_carry = _causal_depthwise_conv(
@@ -112,9 +112,7 @@ def mamba_mix(
     )
     out = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x_in.dtype)
     out = constrain(out, "batch", None, "ssm_inner")
-    if return_state:
-        return out, MambaState(conv=conv_carry, ssm=ssm_final)
-    return out
+    return out, MambaState(conv=conv_carry, ssm=ssm_final)
 
 
 def mamba_decode_mix(
@@ -150,7 +148,7 @@ def mamba_decode_mix(
         + b_dt.astype(jnp.float32)
     )
     A = -jnp.exp(a_log.astype(jnp.float32))
-    a = jnp.exp(dt[0 if False else ...][..., None] * A[None, None])[:, 0]
+    a = jnp.exp(dt[..., None] * A[None, None])[:, 0]
     bu = ((dt * xc)[..., None] * Bm[:, :, None, :])[:, 0]   # [B,di,n]
     h = a * state.ssm.astype(jnp.float32) + bu
     y = jnp.einsum("bdn,bn->bd", h, Cm[:, 0]) + d_skip.astype(jnp.float32) * xc[:, 0]

@@ -9,12 +9,16 @@ single unrolled block where the arch is non-uniform:
   ssm (xLSTM)           [mlstm runs] + [slstm singles] at cfg.slstm_layers
   hybrid (Hymba)        [SWA-hybrid runs] + [global-attn hybrid singles]
 
-The same block numerics serve train, prefill and decode (kv/ssm/cell cache).
-Decode carries a scanned segment's stacked cache through its scan, so each
-step updates the donated cache in place (``_decode_segment``). With no
-sharding rules, serving scans pass an MoE segment's expert weights whole
-and the expert kernel reads each layer's in place (``_whole_weights``);
-the routing's counters ride in the cache (``moe_counters``).
+Each block kind has one forward path, its ``prefill``, and two walkers run
+it over the segments. ``_apply_segment`` is training's forward: it
+rematerialises each layer, scans over per-layer expert weights (each
+layer's gradient then covers its own experts, not the whole stack) and
+drops the caches. ``_prefill_segment`` is serving's: it collects the caches. Decode
+carries a scanned segment's stacked cache through its scan, so each step
+updates the donated cache in place (``_decode_segment``). With no sharding
+rules, serving scans pass an MoE segment's expert weights whole and the
+expert kernel reads each layer's in place (``_whole_weights``); the
+routing's counters ride in the cache (``moe_counters``).
 Each part runs under a ``jax.named_scope`` of ``scopes.SCOPES``: ``embed``,
 ``layers`` (each segment's stack), ``final_norm`` and ``lm_head`` here, the
 block's parts in ``blocks.py``.
@@ -305,23 +309,25 @@ class Model:
 
             def group(xc, gp):
                 def one(xc2, lp):
-                    return attn.apply(cfg, lp, xc2, ctx), None
+                    return attn.prefill(cfg, lp, xc2, ctx)[0], None
                 body = self._maybe_remat(one) if remat else one
                 xc, _ = jax.lax.scan(body, xc, gp["self"])
-                xc = cross.apply(cfg, gp["cross"], xc, ctx)
+                xc = cross.prefill(cfg, gp["cross"], xc, ctx)[0]
                 return xc, None
 
             gbody = self._maybe_remat(group) if remat else group
             x, _ = jax.lax.scan(gbody, x, p)
             return x
         blk = BLOCKS[seg.kind]
+
+        def fwd(xc, lp):
+            return blk.prefill(cfg, lp, xc, ctx)[0]
+
         if not seg.scanned:
-            fn = (self._maybe_remat(lambda xc, lp: blk.apply(cfg, lp, xc, ctx))
-                  if remat else (lambda xc, lp: blk.apply(cfg, lp, xc, ctx)))
-            return fn(x, p)
+            return (self._maybe_remat(fwd) if remat else fwd)(x, p)
 
         def body(xc, lp):
-            return blk.apply(cfg, lp, xc, ctx), None
+            return fwd(xc, lp), None
 
         body = self._maybe_remat(body) if remat else body
         x, _ = jax.lax.scan(body, x, p)

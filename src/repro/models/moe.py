@@ -73,10 +73,6 @@ def moe_dense(x, w_router, we_gate, we_up, we_down, *, k: int) -> jax.Array:
     E = w_router.shape[-1]
     gates, ids, _ = router_topk(x, w_router, k)
     # combine weight per (token, expert): [T,E]
-    comb = jnp.zeros((T, E), jnp.float32)
-    comb = jnp.take_along_axis(
-        comb, ids, axis=1
-    )  # dummy to keep shapes clear; build via scatter below
     comb = jnp.zeros((T, E), jnp.float32).at[jnp.arange(T)[:, None], ids].add(gates)
     ys = _expert_ffn(
         jnp.broadcast_to(x, (E,) + x.shape), we_gate, we_up, we_down

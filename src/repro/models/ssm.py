@@ -52,9 +52,9 @@ def mlstm_chunked(
     state: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     *,
     chunk: int = 256,
-    return_state: bool = False,
 ):
-    """Chunk-parallel mLSTM. Returns h [B, S, H, dv] (and final state)."""
+    """Chunk-parallel mLSTM. Returns h [B, S, H, dv] and the final state
+    (C, n, m)."""
     B, S, H, dk = q.shape
     dv = v.shape[-1]
     L = min(chunk, S)
@@ -115,9 +115,7 @@ def mlstm_chunked(
         chunk_step, (C0, n0, m0), (qf, kf, vf, lf, li)
     )
     h = hs.transpose(1, 0, 3, 2, 4).reshape(B, S, H, dv).astype(v.dtype)
-    if return_state:
-        return h, (Cf, nf, mf)
-    return h
+    return h, (Cf, nf, mf)
 
 
 def mlstm_decode_step(q, k, v, i_pre, f_pre, state):
