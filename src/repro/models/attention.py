@@ -1,6 +1,6 @@
 """Attention for train/prefill/decode, memory-bounded and GSPMD-shardable.
 
-Three code paths:
+Four code paths:
 
   * ``attention``        — train/prefill. Scans over query chunks so scores
     never materialize beyond [B, Sc, KV, G, Skv]; sliding-window attention is
@@ -12,6 +12,9 @@ Three code paths:
     PV contraction reduce over that dim, which GSPMD lowers to the
     flash-decoding collective pattern (small all-reduces), never an
     all-gather of the cache.
+  * ``stacked_decode_attention`` — one new token against one layer of a
+    cache stacked over layers, on one device: the flash-decoding Pallas
+    kernel reads the layer in place and only its filled slots.
 
 Shapes: q [B,S,H,hd], k/v [B,Skv,KV,hd], cache k/v [B,KV,Smax,hd].
 """
@@ -24,8 +27,10 @@ import jax
 import jax.numpy as jnp
 
 from ..distributed.sharding import constrain
+from ..kernels.flash_decode.ops import flash_decode_cache
 
-__all__ = ["attention", "cross_attention", "decode_attention"]
+__all__ = ["attention", "cross_attention", "decode_attention",
+           "stacked_decode_attention"]
 
 NEG_INF = -1e30
 
@@ -203,3 +208,22 @@ def decode_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkgqs,bksh->bqkgh", probs, v_cache)
     return out.reshape(B, 1, H, hd)
+
+
+def stacked_decode_attention(q, k_cache, v_cache, at, length) -> jax.Array:
+    """One-token decode: q [B,1,H,hd] vs layer ``at`` of a cache stacked
+    [*stack,B,KV,W,hd], over its slots ``[0, length)``, block by block up
+    to ``length``. On a TPU the compiled Mosaic kernel reads the stack in
+    place; elsewhere the same kernel runs interpreted on the layer's view
+    (chosen when the program is lowered, by its platform)."""
+    def tpu(q, k, v, n, *at):
+        return flash_decode_cache(q, k, v, at, n)
+
+    def default(q, k, v, n, *at):
+        # interpreted, the kernel's operands become loop state, which the
+        # compiler copies: it gets the layer's own view, not the stack
+        k, v = (c[at] for c in (k, v))
+        return flash_decode_cache(q, k, v, (), n, interpret=True)
+
+    return jax.lax.platform_dependent(q, k_cache, v_cache, length, *at,
+                                      tpu=tpu, default=default)

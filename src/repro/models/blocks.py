@@ -31,8 +31,15 @@ stacks of identical blocks, or single unrolled blocks where the arch is
 non-uniform: Hymba's 3 global-attention layers, xLSTM's sLSTM positions).
 
 Each part of a block runs under a ``jax.named_scope`` from
-``scopes.SCOPES`` (``attn``, ``kv_write``, ``mlp``, ``moe``, ``ssm``,
-``cross_attn``), so a profiler trace names it; scopes are metadata only.
+``scopes.SCOPES`` (``attn``, ``kv_write``, ``attn_cache``, ``mlp``,
+``moe``, ``ssm``, ``cross_attn``), so a profiler trace names it; scopes
+are metadata only.
+
+Decode's attention over the cache reads the layer's slots up to the
+position in place from the stacked cache (the flash-decoding kernel of
+``kernels/flash_decode``) where no sharding rules are active; under rules
+the cache's ``kv_seq`` axis may be sharded, and the einsum of
+``attention.decode_attention`` reads the layer's view instead.
 """
 from __future__ import annotations
 
@@ -45,8 +52,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..configs.base import ArchConfig
-from ..distributed.sharding import constrain
-from .attention import attention, cross_attention, decode_attention, sink_banded_attention
+from ..distributed.sharding import active_rules, constrain
+from .attention import (attention, cross_attention, decode_attention,
+                        sink_banded_attention, stacked_decode_attention)
 from .layers import PT, apply_rope, rms_norm, swiglu
 from .mamba import MambaState, mamba_decode_mix, mamba_mix
 from .moe import moe_ffn
@@ -258,8 +266,16 @@ def _step_attn(cfg: ArchConfig, p, h, cache, ctx: BlockCtx, at=()):
     # decode shards the CACHE over 'model' (flash-decoding); q must keep
     # heads replicated or GSPMD all-gathers the cache slice every layer
     q = constrain(q, "batch", None, None, None)
-    ck, cv, valid = _write_kv(cache, k, v, ctx.pos, ctx, at)
-    o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at), valid)
+    ck, cv, filled = _write_kv(cache, k, v, ctx.pos, ctx, at)
+    with jax.named_scope("attn_cache"):
+        if active_rules() is None:
+            o = stacked_decode_attention(q, ck, cv, at, filled)
+        else:
+            # a kv_seq-sharded cache: GSPMD turns the einsum's reductions
+            # into flash-decoding collectives
+            valid = jnp.arange(ck.shape[len(at) + 2]) < filled
+            o = decode_attention(q, _layer_of(ck, at), _layer_of(cv, at),
+                                 valid)
     return jnp.einsum("bshk,hkd->bsd", o, p["wo"]), ck, cv
 
 
@@ -311,15 +327,16 @@ def _add_counts(stacked, counts, at):
 def _write_kv(cache, k, v, pos, ctx: BlockCtx, at=()):
     """Write one position's k/v [B,1,KV,hd] into layer ``at`` of the cache
     at its slot (ring slots past the sink for windowed layers), in place;
-    returns the new cache arrays and the mask of valid slots."""
+    returns the new cache arrays and the number of slots that hold keys,
+    which are the first ones: ``pos + 1``, or the whole ring once the
+    position has passed its size."""
     W = cache["k"].shape[len(at) + 2]
     if ctx.window == 0:
         slot = pos
-        valid = jnp.arange(W) <= pos
     else:
         ns = ctx.n_sink
         slot = jnp.where(pos < ns, pos, ns + (pos - ns) % ctx.window)
-        valid = (jnp.arange(W) <= pos) | (pos >= W)
+    filled = jnp.minimum(pos + 1, W)
     axes = ("stack",) * len(at) + _kv_axes(ctx)
     with jax.named_scope("kv_write"):
         # [B,1,KV,hd] -> [B,KV,1,hd], through a flat barrier: the compiler
@@ -331,7 +348,7 @@ def _write_kv(cache, k, v, pos, ctx: BlockCtx, at=()):
                 jax.lax.optimization_barrier((k.reshape(-1), v.reshape(-1))))
         ck = constrain(_put_at(cache["k"], k, at, 0, 0, slot), *axes)
         cv = constrain(_put_at(cache["v"], v, at, 0, 0, slot), *axes)
-    return ck, cv, valid
+    return ck, cv, filled
 
 
 def _ffn_decode(cfg: ArchConfig, p, x, res, at=()):

@@ -12,7 +12,9 @@ path says which part of the model the operation belongs to:
   updates its own layer in place, under the block's scopes),
   ``final_norm``, ``lm_head`` (logits or the training loss);
 * block level: ``attn`` (norm, q/k/v, scores, output projection),
-  ``kv_write`` (writing the key/value cache), ``mlp`` (dense FFN),
+  ``kv_write`` (writing the key/value cache), ``attn_cache`` (inside
+  ``attn`` in decode: attention over the cached keys and values, that is
+  scores, softmax and the weighted sum), ``mlp`` (dense FFN),
   ``moe`` (routed experts), ``ssm`` (recurrent mixers: Mamba, mLSTM,
   sLSTM), ``cross_attn`` (attention to image tokens);
 * inside ``moe``, on its dropless sparse path (``moe.moe_sparse``):
@@ -26,4 +28,5 @@ the model without renaming it here reads as unscoped time.
 """
 
 SCOPES = ("embed", "layers", "final_norm", "lm_head", "attn", "kv_write",
-          "mlp", "moe", "ssm", "cross_attn", "router", "dispatch", "experts")
+          "attn_cache", "mlp", "moe", "ssm", "cross_attn", "router",
+          "dispatch", "experts")

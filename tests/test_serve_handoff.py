@@ -28,11 +28,11 @@ def dense():
     return model, params, prompts
 
 
-def _plain_greedy(model, params, prompts):
+def _plain_greedy(model, params, prompts, rules=None, smax=SMAX):
     """Greedy decoding one batch at a time: argmax, read to the host, feed
     the tokens back."""
-    prefill = jax.jit(make_prefill_fn(model, None, SMAX))
-    decode = jax.jit(make_decode_fn(model, None))
+    prefill = jax.jit(make_prefill_fn(model, rules, smax))
+    decode = jax.jit(make_decode_fn(model, rules))
     out = []
     for i in range(0, len(prompts), B):
         logits, cache = prefill(params,
@@ -55,6 +55,39 @@ def test_served_tokens_equal_a_plain_greedy_loop(dense):
     out = eng.run(batch_size=B)
     assert [out[r] for r in rids] == _plain_greedy(model, params, prompts)
     assert all(type(t) is int for r in rids for t in out[r])
+
+
+@pytest.mark.parametrize("arch,prompt,smax", [("qwen2-1.5b", S, SMAX),
+                                               ("hymba-1.5b", 20, 32)])
+def test_kernel_serves_the_tokens_of_the_einsum(arch, prompt, smax):
+    """Without sharding rules, decode attention is the flash-decoding
+    kernel (interpreted here); under rules (one device) it is the einsum.
+    Both serve the same tokens. Hymba's windowed layer keeps a ring of 8
+    sink and 16 window slots, which its 28 prompt positions (meta tokens
+    included) have already wrapped; its global layer's 40 slots take one
+    block."""
+    from repro.distributed.sharding import rules_for
+    from repro.launch.mesh import single_device_mesh
+
+    cfg = dataclasses.replace(REGISTRY[arch].reduced(), n_layers=2)
+    model = build_model(cfg, remat=False)
+    params = model.init(jax.random.PRNGKey(2), jnp.float32)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, prompt, dtype=np.int32)
+               for _ in range(2 * B)]
+    cache = model.abstract_cache(B, smax, jnp.float32)
+    tokens = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    rules = rules_for(single_device_mesh(), n_heads=cfg.n_heads,
+                      d_ff=cfg.d_ff)
+    for r, kernel in ((None, True), (rules, False)):
+        jaxpr = str(jax.make_jaxpr(make_decode_fn(model, r))(
+            params, cache, tokens))
+        assert ("pallas_call" in jaxpr) == kernel
+    eng = ServeEngine(model, params, smax=smax)
+    rids = [eng.submit(p, max_new=NEW) for p in prompts]
+    out = eng.run(batch_size=B)
+    assert [out[r] for r in rids] == _plain_greedy(model, params, prompts,
+                                                   rules, smax)
 
 
 def test_no_token_reaches_the_host_before_the_last_decode(dense):

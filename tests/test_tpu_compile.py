@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     prev = jax.config.jax_enable_compilation_cache
@@ -37,10 +37,15 @@ def one_chip():
                     platform="tpu", topology_name="v5e:2x2")
             except Exception as e:
                 pytest.skip(f"no v5e:2x2 topology can be described: {e}")
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype):
@@ -186,3 +191,74 @@ def test_qwen3_moe_serving_fits_one_chip(one_chip, program):
              - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert total < 16e9, (total, ma.temp_size_in_bytes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _attn_cache_kernels(text):
+    """The Mosaic kernels of a compiled program that run under the
+    ``attn_cache`` scope, as their HLO lines."""
+    return [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln
+            and "/attn_cache/" in ln]
+
+
+@pytest.mark.parametrize("arch,layers", [("qwen2-1.5b", 28),
+                                         ("qwen3-moe-30b-a3b", 8)])
+def test_decode_attention_reads_the_stacked_cache_in_place(one_chip, arch,
+                                                           layers):
+    """The serving cells' decode steps (batch 32, 768 slots): attention
+    over the cache is one Mosaic kernel per layer, fed the whole stacked
+    cache (a bitcast of it: layers, batch × kv heads, slots, head), with no
+    copy of the cache and scratch under a tenth of it."""
+    import dataclasses
+    import re
+
+    from repro.configs import REGISTRY
+    from repro.models import build_model
+    from repro.serve.engine import make_decode_fn
+
+    cfg = dataclasses.replace(REGISTRY[arch], n_layers=layers)
+    model = build_model(cfg)
+    B, SMAX = 32, 768
+
+    def sds(x):
+        return _sds(one_chip, x.shape, x.dtype)
+
+    cache = jax.tree_util.tree_map(sds, model.abstract_cache(B, SMAX))
+    compiled = jax.jit(make_decode_fn(model, None), donate_argnums=(1,)).lower(
+        jax.tree_util.tree_map(sds, model.abstract()), cache,
+        _sds(one_chip, (B, 1), jnp.int32)).compile()
+    text = compiled.as_text()
+    (kernel,) = _attn_cache_kernels(text)
+    merged = "bf16[%d,%d,%d,%d]{3,2,1,0}" % (
+        layers, B * cfg.n_kv_heads, SMAX, cfg.hd)
+    assert kernel.count(merged) == 2, kernel[:400]
+    stacked = "bf16[%s]" % ",".join(map(str, cache["segments"][0]["k"].shape))
+    copies = re.findall(r"= (?:%s|%s)\S* copy\(" % (
+        re.escape(stacked), re.escape(merged.split("{")[0])), text)
+    assert not copies, copies
+    kv_bytes = sum(cache["segments"][0][n].size * 2 for n in ("k", "v"))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * kv_bytes
+
+
+def test_sharded_decode_keeps_the_einsum(topo):
+    """Under sharding rules (a 2x2 mesh, the cache's ``kv_seq`` axis over
+    'model'), decode attention stays the einsum, which the partitioner
+    turns into flash-decoding collectives: no Mosaic kernel runs under
+    ``attn_cache``, while the scope itself is there."""
+    import dataclasses
+
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs import REGISTRY
+    from repro.configs.base import ShapeSpec
+    from repro.launch.programs import build_program
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = dataclasses.replace(REGISTRY["qwen2-1.5b"], n_layers=2)
+    prog = build_program(cfg, ShapeSpec("decode_768", 768, 32, "decode"),
+                         mesh)
+    assert prog.rules.table["kv_seq"] == "model"
+    text = prog.lower().compile().as_text()
+    assert not _attn_cache_kernels(text)
+    assert "/attn_cache/" in text
